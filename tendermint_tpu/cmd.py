@@ -63,30 +63,23 @@ def cmd_init(args) -> int:
     return 0
 
 
-def cmd_start(args) -> int:
-    """(cmd/tendermint/commands/run_node.go) run a node until SIGINT."""
+def build_node(args):
+    """The node `start` runs, assembled from ``args.home`` (config,
+    genesis and keys as `init`/`testnet` wrote them) plus the start
+    command's overrides. Also switches on the persistent XLA compile
+    cache: the batched-verify kernels take minutes to compile cold, and
+    without it every fresh node process pays that on its first
+    device-routed batch. The cache is placed by
+    ``JAX_COMPILATION_CACHE_DIR`` or, unset, at ``<checkout>/.jax_cache``
+    — never under the node home, which is a temp directory in every
+    harness (libs/compilecache.py holds the rule and the host-fingerprint
+    warning)."""
+    from .libs.compilecache import enable_compile_cache
     from .node import Node
 
-    logging.basicConfig(
-        level=getattr(logging, args.log_level.upper(), logging.INFO),
-        format="%(asctime)s %(name)s %(levelname).1s %(message)s")
-    # persistent XLA compile cache: the batched-verify kernels take minutes
-    # to compile cold; without this every fresh node process pays that on
-    # its first device-routed batch (TMTPU_JAX_CACHE overrides, e.g. the
-    # e2e runner points all subprocess nodes at one shared cache). The
-    # helper also fingerprints the cache dir and warns LOUDLY when it was
-    # built on a host with different CPU features — the cpu_aot_loader
-    # SIGILL risk otherwise buried in stderr (MULTICHIP_r05.json).
-    try:
-        from .libs.compilecache import enable_compile_cache
-
-        cache = os.environ.get("TMTPU_JAX_CACHE") or os.path.join(
-            args.home, ".jax_cache")
-        warn = enable_compile_cache(cache)
-        if warn:
-            logging.getLogger("tmtpu.node").warning("%s", warn)
-    except Exception:
-        pass
+    warn = enable_compile_cache()
+    if warn:
+        logging.getLogger("tmtpu.node").warning("%s", warn)
     cfg = Config.load(args.home)
     if args.p2p_laddr:
         cfg.p2p.laddr = args.p2p_laddr
@@ -97,7 +90,16 @@ def cmd_start(args) -> int:
     if args.proxy_app:
         cfg.base.proxy_app = args.proxy_app
     cfg.validate_basic()
-    node = Node.default(cfg)
+    return Node.default(cfg)
+
+
+def cmd_start(args) -> int:
+    """(cmd/tendermint/commands/run_node.go) run a node until SIGINT."""
+    logging.basicConfig(
+        level=getattr(logging, args.log_level.upper(), logging.INFO),
+        format="%(asctime)s %(name)s %(levelname).1s %(message)s")
+    node = build_node(args)
+    cfg = node.config
 
     # TMTPU_TRACE_OUT=<prefix>: run the whole node under the span tracer and
     # write <prefix>-<pid>.json (Chrome trace-event JSON) on shutdown, so a

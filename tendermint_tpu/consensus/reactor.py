@@ -110,7 +110,7 @@ class PeerState:
         from facts to guesses. Gossip marks a vote/part as delivered when
         it SENDS it (reactor.go PickSendVote semantics) — sound over the
         reliable TCP transport, but a lossy or blackholed link (partition,
-        dying relay, chaos LinkPolicy) eats sends silently and the bitmaps
+        dying peer, chaos LinkPolicy) eats sends silently and the bitmaps
         then claim the peer has data it never saw: catchup stops and the
         link wedges permanently. After ``stall_s`` without a single
         message from the peer, clear what we think we delivered so the

@@ -37,8 +37,8 @@ from .breaker import classify_device_error, device_breaker
 logger = logging.getLogger("tmtpu.batch")
 
 # below this many signatures the host scalar loop beats a device round-trip.
-# The break-even point depends on per-dispatch overhead: ~100 us on a local
-# chip, ~100 ms through a remote relay — so "auto" calibrates once.
+# The break-even point depends on per-dispatch overhead, which differs by
+# orders of magnitude between attachments — so "auto" calibrates once.
 DEFAULT_DEVICE_THRESHOLD = 16
 _HOST_SIGS_PER_SEC_ESTIMATE = 7000.0  # OpenSSL verify ~140 us/op
 _calibrated_threshold: Optional[int] = None
@@ -46,7 +46,7 @@ _calibrated_threshold: Optional[int] = None
 
 # routed batches must never lose to the scalar loop: bias the calibrated
 # break-even up so near-threshold commits stay on host (the device win at
-# the margin is ~0, the loss through a slow relay is 5-10x)
+# the margin is ~0, the loss on a slow attachment is large)
 _CALIBRATION_SAFETY = 1.25
 
 
@@ -56,10 +56,9 @@ def device_threshold() -> int:
 
     The probe carries a fresh ~32KB payload (a ~150-sig commit's wire
     weight): a payload-free jit call measures only the fixed dispatch cost
-    and badly underestimates relay-attached devices, which is how
-    sub-threshold commits ended up routed to a path 5x slower than the
-    scalar loop (BENCH_r05 verify_commit_150_device_routed at 0.18x).
-    Fresh random bytes per call defeat relay result-caching."""
+    and underestimates a device whose transfers are slow, which once routed
+    sub-threshold commits to a path slower than the scalar loop. Fresh
+    random bytes per call keep any result cache out of the reading."""
     global _calibrated_threshold
     env = os.environ.get("TMTPU_DEVICE_THRESHOLD")
     if env:
@@ -90,7 +89,7 @@ def device_threshold() -> int:
         except Exception as e:
             # calibration failure is routing advice, not correctness: fall
             # back to the static default — but say so, a silent except here
-            # once hid a broken relay for a whole bench run
+            # once hid a broken device path for a whole bench run
             logger.warning("device-threshold calibration failed (%s); "
                            "using default %d", e, DEFAULT_DEVICE_THRESHOLD)
             _calibrated_threshold = DEFAULT_DEVICE_THRESHOLD

@@ -70,11 +70,6 @@ def _sharded_step(mesh: Mesh):
         if hit is not None:
             return hit
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older JAX
-        from jax.experimental.shard_map import shard_map
-
     def full_step(blocks, nblk, s_words, power_limbs):
         verdict = _verify_kernel.__wrapped__(blocks, nblk, s_words)
         # (8, B, 128) int32 8-bit limb planes; zero out rejected signatures
@@ -83,15 +78,12 @@ def _sharded_step(mesh: Mesh):
         total_limbs = jax.lax.psum(local, axis_name=AXIS)
         return verdict, total_limbs
 
-    specs = dict(
+    # check_vma off: replication checking chokes on scan carries that
+    # become varying
+    step = jax.jit(jax.shard_map(
+        full_step, mesh=mesh, check_vma=False,
         in_specs=(BLOCK_SPEC, FLAG_SPEC, WORD_SPEC, WORD_SPEC),
-        out_specs=(FLAG_SPEC, P()),
-    )
-    try:  # replication checking chokes on scan carries that become varying
-        sharded = shard_map(full_step, mesh=mesh, check_vma=False, **specs)
-    except TypeError:  # older JAX spells it check_rep
-        sharded = shard_map(full_step, mesh=mesh, check_rep=False, **specs)
-    step = jax.jit(sharded)
+        out_specs=(FLAG_SPEC, P())))
     with _STEP_LOCK:
         # a racing builder may have landed first; keep the winner so every
         # caller shares one trace cache

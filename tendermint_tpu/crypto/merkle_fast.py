@@ -15,10 +15,13 @@ between hashlib / numpy / device and owns breaker + threshold policy.
 
 from __future__ import annotations
 
+import logging
 import struct
 from typing import List
 
 import numpy as np
+
+logger = logging.getLogger("tmtpu.merkle")
 
 _K = np.array([
     0x428a2f98, 0x71374491, 0xb5c0fbcf, 0xe9b5dba5,
@@ -114,7 +117,10 @@ def device_ready() -> bool:
             import jax
 
             _device_state.append(bool(jax.devices()))
-        except Exception:
+        except (ImportError, RuntimeError) as e:
+            # jax.devices() raises RuntimeError when no backend can be
+            # initialized; said once (the probe is cached), never silent
+            logger.warning("merkle device tier off: no jax backend (%s)", e)
             _device_state.append(False)
     return _device_state[0]
 

@@ -1,6 +1,6 @@
 """Per-segment dispatch-phase telemetry for the device verification plane.
 
-The flagship 23x win (PROFILE_r05.json) was found by timing three phases of
+The flagship's first large win was found by timing three phases of
 every device dispatch by hand — host packing, the async kernel dispatch,
 and the fetch wait for verdicts — across eight throwaway scripts. This
 module makes those stamps a permanent, always-on part of the dispatch path
@@ -201,7 +201,7 @@ class Segment:
         return self
 
     def abandon(self) -> "Segment":
-        """Close a never-fetched segment (a relay fetch or a sibling
+        """Close a never-fetched segment (a device fetch or a sibling
         segment raised): drains the in-flight gauge if it dispatched, and
         marks the record closed either way — so a pipeline worker still
         mid-pack when its call aborts cannot increment the gauge later

@@ -45,7 +45,7 @@ DEFAULT_MIN_DEVICE_BATCH = 16
 DEFAULT_MAX_BATCH = 1024
 DEFAULT_DEADLINE_S = 0.003
 # consensus liveness bound: if a device flush hasn't produced verdicts in
-# this long (cold XLA compile on a fresh node, relay stall), the batch is
+# this long (cold XLA compile on a fresh node, device stall), the batch is
 # re-verified on the host scalar path and later flushes stay host-side
 # until the device call finally completes. Found in the wild: a catchup
 # vote burst on a fresh node dispatched a cold-compile flush and consensus
@@ -171,7 +171,7 @@ class BatchVoteVerifier:
                 def _device_verify():
                     # chaos seam: an armed `device.vote_flush` site raises
                     # on the executor thread, exactly where a real kernel /
-                    # relay failure would surface. The ed25519_jax import
+                    # runtime failure would surface. The ed25519_jax import
                     # lives here too so a broken jax install takes the same
                     # host-fallback + breaker path as a runtime failure
                     # instead of failing every pending preverify future

@@ -629,14 +629,14 @@ class CryptoMetrics:
 class DeviceMetrics:
     """The device dispatch pipeline (crypto/phases.py recorder): per-segment
     pack / dispatch / fetch phase latencies, per-device dispatch traffic,
-    and the pipeline-overlap ratio — the self-measuring successor to the
-    hand-built PROFILE_r05.json relay cost model. Offload engines are
+    and the pipeline-overlap ratio — the dispatch cost model, measured by
+    the system itself. Offload engines are
     designed from exactly this stage-occupancy breakdown (arXiv 2112.02229)
     and committee-consensus throughput studies attribute wins through it
     (arXiv 2302.00418)."""
 
-    #: phase times span ~100 us (CPU pack of a small chunk) to multi-second
-    #: relay fetches
+    #: phase times span ~100 us (CPU pack of a small chunk) to a
+    #: multi-second fetch behind a cold compile
     PHASE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                      0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 

@@ -1,6 +1,8 @@
 """tools/bench_compare.py: the bench-regression gate — exit codes,
 per-metric thresholds, lower-is-better latency gating, driver-format
-parsing, and the real BENCH_r*.json history staying machine-checkable."""
+parsing (raw JSONL and the driver's {"tail": ...} record), and a multi-run
+history staying machine-checkable. Every run is rows the test writes: the
+numbers are made up, the gate's behaviour on them is what is asserted."""
 
 import json
 import os
@@ -39,32 +41,74 @@ def test_self_test_passes():
     assert "self-test OK" in res.stdout
 
 
-def test_real_history_trips_on_pack_share_creep():
-    """r04 -> r05 improved every throughput metric BUT let the flagship's
-    packing share creep 7% -> 11.1% (+59%) with nothing watching. With the
-    pack-share ratio gated lower-is-better, the checked-in history itself
-    must now trip exit 1 on exactly that metric — and on nothing else."""
-    r04 = os.path.join(REPO, "BENCH_r04.json")
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    res = _run(r04, r05)
+def _write_driver_record(path, metrics, n=1):
+    """The driver's record of a bench run: the JSONL stream rides in
+    "tail" behind whatever the process logged first."""
+    tail = "WARNING: some start-up noise the parser must skip\n" + "".join(
+        json.dumps({"metric": m, "value": v, "unit": unit,
+                    "vs_baseline": 1.0}) + "\n"
+        for m, (v, unit) in metrics.items())
+    last = list(metrics.items())[-1]
+    with open(path, "w") as f:
+        json.dump({"n": n, "cmd": "python bench.py", "rc": 0, "tail": tail,
+                   "parsed": {"metric": last[0], "value": last[1][0],
+                              "unit": last[1][1], "vs_baseline": 1.0}}, f)
+
+
+#: three runs of one history: every throughput metric improves run over
+#: run, while the flagship's packing share creeps 0.070 -> 0.111 (+59%)
+#: between the last two
+_HISTORY = [
+    {"verify_commit_150_vals_sigs_per_sec": (5000.0, "sigs/s"),
+     "fast_sync_1000_vals_blocks_per_sec": (20.0, "blocks/s"),
+     "verify_commit_10k_sigs_per_sec": (40000.25, "sigs/s")},
+    {"verify_commit_150_vals_sigs_per_sec": (6000.0, "sigs/s"),
+     "fast_sync_1000_vals_blocks_per_sec": (30.0, "blocks/s"),
+     "localnet_4node_tx_commit_latency_p50": (1.3, "s"),
+     "verify_commit_10k_breakdown_pack_share": (0.07, "ratio"),
+     "verify_commit_10k_sigs_per_sec": (90000.5, "sigs/s")},
+    {"verify_commit_150_vals_sigs_per_sec": (7000.0, "sigs/s"),
+     "fast_sync_1000_vals_blocks_per_sec": (50.0, "blocks/s"),
+     "localnet_4node_tx_commit_latency_p50": (1.1, "s"),
+     "verify_commit_10k_breakdown_pack_share": (0.111, "ratio"),
+     "verify_commit_10k_sigs_per_sec": (150000.75, "sigs/s")},
+]
+
+
+def _history(tmp_path):
+    paths = []
+    for i, metrics in enumerate(_HISTORY, start=1):
+        path = str(tmp_path / f"run{i}.json")
+        _write_driver_record(path, metrics, n=i)
+        paths.append(path)
+    return paths
+
+
+def test_real_history_trips_on_pack_share_creep(tmp_path):
+    """A run that improves every throughput metric BUT lets the flagship's
+    packing share creep 7% -> 11.1% (+59%): with the pack-share ratio
+    gated lower-is-better, the history itself must trip exit 1 on exactly
+    that metric — and on nothing else."""
+    _old, prev, last = _history(tmp_path)
+    res = _run(prev, last)
     assert res.returncode == 1, res.stdout + res.stderr
     fail = next(l for l in res.stdout.splitlines() if l.startswith("FAIL"))
     assert "verify_commit_10k_breakdown_pack_share" in fail
     assert fail.startswith("FAIL: 1 regression(s)"), fail
-    # loosening that one metric's threshold restores a clean r04 -> r05
+    # loosening that one metric's threshold restores a clean pair
     res2 = _run("--threshold",
-                "verify_commit_10k_breakdown_pack_share=0.6", r04, r05)
+                "verify_commit_10k_breakdown_pack_share=0.6", prev, last)
     assert res2.returncode == 0, res2.stdout
     assert "OK: no regressions" in res2.stdout
     bc = _mod()
-    run = bc.load_bench(r05)
+    run = bc.load_bench(last)  # the driver's record format parses
     assert run["verify_commit_10k_sigs_per_sec"]["value"] > 150000
 
 
 def test_degraded_flagship_trips_gate(tmp_path):
     bc = _mod()
-    r05 = bc.load_bench(os.path.join(REPO, "BENCH_r05.json"))
-    degraded = dict(r05)
+    last = _history(tmp_path)[-1]
+    degraded = dict(bc.load_bench(last))
     rec = dict(degraded["verify_commit_10k_sigs_per_sec"])
     rec["value"] = rec["value"] * 0.5  # 50% < the 30% default threshold
     degraded["verify_commit_10k_sigs_per_sec"] = rec
@@ -72,13 +116,13 @@ def test_degraded_flagship_trips_gate(tmp_path):
     with open(new, "w") as f:
         for line in degraded.values():
             f.write(json.dumps(line) + "\n")
-    res = _run(os.path.join(REPO, "BENCH_r05.json"), new)
+    res = _run(last, new)
     assert res.returncode == 1, res.stdout
     assert "REGRESSION" in res.stdout
     assert "verify_commit_10k_sigs_per_sec" in res.stdout
     # loosening that one metric's threshold un-trips it
     res2 = _run("--threshold", "verify_commit_10k_sigs_per_sec=0.6",
-                os.path.join(REPO, "BENCH_r05.json"), new)
+                last, new)
     assert res2.returncode == 0, res2.stdout
 
 
@@ -103,9 +147,9 @@ def test_missing_gated_metric_fails(tmp_path):
     assert "MISSING" in res.stdout
 
 
-def test_trajectory_table_over_history():
-    files = [os.path.join(REPO, f"BENCH_r0{i}.json") for i in (3, 4, 5)]
-    # the pack-share gate trips on the raw r04 -> r05 pair (see above);
+def test_trajectory_table_over_history(tmp_path):
+    files = _history(tmp_path)
+    # the pack-share gate trips on the raw last pair (see above);
     # loosened here so this test isolates the trajectory rendering
     res = _run("--threshold",
                "verify_commit_10k_breakdown_pack_share=0.6", *files)
@@ -113,7 +157,7 @@ def test_trajectory_table_over_history():
     # all three runs' flagship values appear in one row
     line = next(l for l in res.stdout.splitlines()
                 if l.startswith("verify_commit_10k_sigs_per_sec "))
-    assert "157880" in line and "47384" in line
+    assert "150000" in line and "90000" in line and "40000" in line
     # the gated pack share joined the trajectory table
     assert any(l.startswith("verify_commit_10k_breakdown_pack_share")
                for l in res.stdout.splitlines())

@@ -227,7 +227,7 @@ def test_failed_fetch_drains_inflight_gauge(monkeypatch, device_metrics):
 
     class _BrokenDev:
         def __array__(self, dtype=None, copy=None):
-            raise RuntimeError("relay dropped the fetch")
+            raise RuntimeError("device dropped the fetch")
 
     def fake_dispatch(pks, msgs, sigs, chunk):
         phases.mark_pack_done()
@@ -235,7 +235,7 @@ def test_failed_fetch_drains_inflight_gauge(monkeypatch, device_metrics):
 
     monkeypatch.setattr(V, "_dispatch_stream", fake_dispatch)
     monkeypatch.setattr(V, "SEG_MIN_SIGS", 256)
-    with pytest.raises(RuntimeError, match="relay dropped"):
+    with pytest.raises(RuntimeError, match="device dropped"):
         # every segment dispatches (gauge +1 each); segment 0's fetch blows
         V._verify_segmented([b"\x01" * 32] * 512, [b"m"] * 512,
                             [b"\x02" * 64] * 512, V.LANE)

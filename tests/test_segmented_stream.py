@@ -1,6 +1,6 @@
 """Unit tests for the segmented double-buffered batch_verify_stream path
 (the flagship 10k-validator optimization: segment i+1's pack+transfer
-overlaps segment i's device compute through the relay).
+overlaps segment i's device compute).
 
 The device kernel itself is covered differentially by test_sparse_verify /
 test_ed25519_jax; here the dispatch step is faked so the orchestration
@@ -98,10 +98,10 @@ def test_stream_entry_routes_large_batches_to_segments(monkeypatch):
 
 def test_segmented_worker_exception_propagates(monkeypatch):
     def boom(pks, msgs, sigs, chunk):
-        raise RuntimeError("relay dropped the connection")
+        raise RuntimeError("device dropped the connection")
 
     monkeypatch.setattr(V, "_dispatch_stream", boom)
-    with pytest.raises(RuntimeError, match="relay dropped"):
+    with pytest.raises(RuntimeError, match="device dropped"):
         V._verify_segmented([b"\x01" * 32] * 512, [b"m"] * 512,
                             [b"\x02" * 64] * 512, V.LANE)
 

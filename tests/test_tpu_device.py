@@ -1,7 +1,9 @@
 """On-device differential suite: the batch verifier vs the host spec on the
 REAL accelerator backend.
 
-Run with ``TM_ON_DEVICE=1 python -m pytest tests/test_tpu_device.py -q``.
+Run with ``TM_ON_DEVICE=1 python -m pytest tests/test_tpu_device.py -q`` on a
+machine with the chip (``python chip_smoke.py`` runs the same corpora there
+as part of the end-to-end smoke).
 The default suite pins CPU (see conftest.py); these tests exist because the
 round-1 kernel returned *wrong answers only on the TPU backend* (a roll-based
 column build in field.mul miscompiled under fori_loop) while the CPU suite was
@@ -19,6 +21,14 @@ import pytest
 from tendermint_tpu.crypto import ed25519 as host
 from tendermint_tpu.crypto.ed25519_jax import batch_verify
 
+# ONE corpus generator, shared with the chip smoke (repo root on sys.path:
+# the suite runs as `python -m pytest` from there)
+from chip_smoke import (
+    adversarial_corpus,
+    edge_encodings,
+    votelike_stream_corpus,
+)
+
 ON_DEVICE = os.environ.get("TM_ON_DEVICE") == "1"
 
 pytestmark = pytest.mark.skipif(
@@ -32,35 +42,10 @@ def _device_is_accelerator():
     return jax.default_backend() != "cpu"
 
 
-def _corpus(n, seed):
-    """n (pk, msg, sig) tuples: ~60% valid, rest adversarial."""
-    rng = np.random.default_rng(seed)
-    pks, msgs, sigs = [], [], []
-    for i in range(n):
-        sd = rng.bytes(32)
-        msg = rng.bytes(1 + int(rng.integers(0, 64)))
-        pk = host.pubkey_from_seed(sd)
-        sig = host.sign(sd + pk, msg)
-        kind = i % 10
-        if kind == 6:  # corrupted R
-            sig = bytes([sig[0] ^ 1]) + sig[1:]
-        elif kind == 7:  # corrupted s
-            sig = sig[:32] + bytes([sig[32] ^ 1]) + sig[33:]
-        elif kind == 8:  # non-canonical s (s + L)
-            s = int.from_bytes(sig[32:], "little") + host.L
-            sig = sig[:32] + s.to_bytes(32, "little")
-        elif kind == 9:  # wrong message
-            msg = msg + b"!"
-        pks.append(pk)
-        msgs.append(msg)
-        sigs.append(sig)
-    return pks, msgs, sigs
-
-
 @pytest.mark.parametrize("n", [1, 16, 20, 127, 128, 129, 1024])
 def test_device_matches_host_spec(n):
     assert _device_is_accelerator(), "suite must run on the accelerator backend"
-    pks, msgs, sigs = _corpus(n, seed=n)
+    pks, msgs, sigs = adversarial_corpus(n, seed=n)
     got = np.asarray(batch_verify(pks, msgs, sigs))
     want = np.array(
         [host.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)], dtype=bool
@@ -71,19 +56,7 @@ def test_device_matches_host_spec(n):
 
 def test_device_rejects_x0_sign1_and_noncanonical_y():
     assert _device_is_accelerator()
-    bad_pks, msgs, sigs = [], [], []
-    # x=0 with sign bit set (y=1 / y=p-1): must reject
-    for y in (1, host.P - 1):
-        bad_pks.append((y | 1 << 255).to_bytes(32, "little"))
-    # y >= p encodings (non-canonical): must reject
-    for y in (host.P, host.P + 1):
-        bad_pks.append(y.to_bytes(32, "little"))
-    s = 7
-    sB = host._pt_mul(s, (host.B[0], host.B[1], 1, host.B[0] * host.B[1] % host.P))
-    sig = host._pt_encode(sB) + s.to_bytes(32, "little")
-    for _ in bad_pks:
-        msgs.append(b"forged")
-        sigs.append(sig)
+    bad_pks, msgs, sigs = edge_encodings()
     got = np.asarray(batch_verify(bad_pks, msgs, sigs))
     want = np.array(
         [host.verify(p, m, s_) for p, m, s_ in zip(bad_pks, msgs, sigs)], dtype=bool
@@ -116,28 +89,9 @@ def test_device_segmented_pipeline_matches_host():
     from tendermint_tpu.crypto.ed25519_jax import verify as V
 
     n = max(2 * V.SEG_MIN_SIGS, 4 * 2048)
-    rng = np.random.default_rng(41)
-    base = bytes(rng.bytes(100))
-    pks, msgs, sigs = [], [], []
-    sd = rng.bytes(32)
-    pk = host.pubkey_from_seed(sd)
-    for i in range(n):
-        m = bytearray(base)
-        m[40:48] = int(i).to_bytes(8, "little")  # vote-like: sparse diffs
-        m = bytes(m)
-        sig = host.sign(sd + pk, m)
-        pks.append(pk)
-        msgs.append(m)
-        sigs.append(sig)
-    # rejects at every real segment boundary (derive from _segment_sizes so
-    # env overrides of SEG_CHUNKS/SEG_MIN_SIGS keep the coverage honest)
-    bad = {0, 1, n // 2, n - 1}
-    row = 0
-    for size in V._segment_sizes(-(-n // 2048))[:-1]:
-        row += size * 2048
-        bad |= {row - 1, row, row + 1}
-    for i in bad:
-        sigs[i] = sigs[i][:32] + bytes(32)
+    # rejects at every real segment boundary (derived from _segment_sizes,
+    # so env overrides of SEG_CHUNKS/SEG_MIN_SIGS keep the coverage honest)
+    pks, msgs, sigs, bad = votelike_stream_corpus(n, seed=41)
     got = np.asarray(V.batch_verify_stream(pks, msgs, sigs, chunk=2048))
     want = np.ones(n, dtype=bool)
     want[list(bad)] = False

@@ -1,5 +1,5 @@
 """Vote-batcher liveness: a device flush that stalls (cold XLA compile on a
-fresh node, relay hang) must NOT wedge consensus — the batch re-verifies on
+fresh node, device hang) must NOT wedge consensus — the batch re-verifies on
 the host within device_timeout_s and later flushes stay host-side until the
 device call completes. Found via a SIGUSR1 stack dump of a localnet node
 stuck at one height with every _preverify_and_forward task pending."""
