@@ -170,8 +170,10 @@ def test_verify_commit_light_batched_window(one_val_genesis, monkeypatch):
     conns.stop()
 
 
-def test_verify_commit_light_batched_device_path(one_val_genesis):
-    """>=16 sigs in one call routes to the jax kernel; decisions unchanged."""
+def test_verify_commit_light_batched_device_path(one_val_genesis,
+                                                 device_standin):
+    """>=16 sigs in one call take the device route (its seam stood in);
+    decisions unchanged."""
     pv, genesis = one_val_genesis
     state, _ss, bs, conns, _app = build_chain(20, pv, genesis)
     entries = []
@@ -181,6 +183,7 @@ def test_verify_commit_light_batched_device_path(one_val_genesis):
         entries.append((state.validators, CHAIN_ID, bid, h, bs.load_seen_commit(h)))
     results = verify_commit_light_batched(entries)
     assert all(r is None for r in results)
+    assert device_standin.calls == [18]
     conns.stop()
 
 

@@ -142,7 +142,7 @@ def breaker_knobs():
         breaker_mod.set_breaker_metrics(None)
 
 
-def test_batch_verifier_breaker_cycle(breaker_knobs):
+def test_batch_verifier_breaker_cycle(breaker_knobs, device_standin):
     """Injected device faults → host fallback with identical verdicts →
     breaker opens (zero device attempts, via metrics) → half-open probe
     restores the device route when injection stops."""

@@ -3,6 +3,10 @@
 Byte-identical accept/reject is the contract (SURVEY.md north star):
 every decision of ed25519_jax.batch_verify must equal
 tendermint_tpu.crypto.ed25519.verify on the same inputs.
+
+This file builds the ONE ``_verify_kernel`` program of a tier-1 run (128
+lanes, one SHA block: every batch here is at most 128 rows of messages
+under 48 bytes — keep it so, a second shape is a second build of minutes).
 """
 
 import random
@@ -103,7 +107,7 @@ def test_identity_pubkey_with_forged_sig():
 
 
 def test_large_batch_and_padding():
-    cases = _valid_cases(5, seed=9)  # pads 5 -> 64
+    cases = _valid_cases(5, seed=9)  # pads 5 -> 128 lanes
     bad = list(cases[2])
     bad[2] = bad[2][:63] + bytes([bad[2][63] ^ 0x40])
     cases[2] = tuple(bad)
@@ -123,3 +127,22 @@ def test_wrong_lengths():
         (pub + b"\x00", b"m", sig),
         (pub, b"m", sig + b"\x00"),
     ])
+
+
+def test_openssl_path_rejects_x0_sign1_pubkeys():
+    """Regression (consensus-split): x=0 with sign bit 1 encodings must be
+    rejected by the OpenSSL fast path, matching the strict spec + TPU path."""
+    from tendermint_tpu.crypto import Ed25519PubKey
+
+    for y in (1, ed.P - 1):
+        pub = (y | 1 << 255).to_bytes(32, "little")
+        s = 7
+        sB = ed._pt_mul(s, (ed.B[0], ed.B[1], 1, ed.B[0] * ed.B[1] % ed.P))
+        sig = ed._pt_encode(sB) + s.to_bytes(32, "little")
+        assert not ed.verify(pub, b"forged", sig)
+        assert not Ed25519PubKey(pub).verify_signature(b"forged", sig)
+        assert not batch_verify([pub], [b"forged"], [sig])[0]
+    # the unset-sign siblings are legitimately decodable points — paths agree
+    for y in (1, ed.P - 1):
+        pub = y.to_bytes(32, "little")
+        assert ed._pt_decode(pub) is not None

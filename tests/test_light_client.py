@@ -226,9 +226,10 @@ def test_light_client_against_live_node(tmp_path):
     asyncio.run(run())
 
 
-def test_verify_chain_batched_parity():
+def test_verify_chain_batched_parity(device_standin):
     """verify_chain_batched must make the same accept/reject decisions as
-    stepwise verify(), with all signatures in one batch."""
+    stepwise verify(), with all signatures in one batch (on the device
+    route, its seam stood in)."""
     from tendermint_tpu.light.verifier import verify_chain_batched
 
     keys = _keys(0x80, 4)
@@ -238,6 +239,7 @@ def test_verify_chain_batched_parity():
 
     # happy path
     verify_chain_batched(blocks[1], chain, 3600.0, now, 10.0)
+    assert len(device_standin.calls) == 1  # one dispatch for the chain
 
     # corrupt one signature mid-chain: same error as the stepwise path
     import copy

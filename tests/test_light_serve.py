@@ -221,12 +221,15 @@ def _mixed_ed25519_batch():
     ]
 
 
-def test_coalesced_verdicts_match_scalar_ed25519():
+def test_coalesced_verdicts_match_scalar_ed25519(device_standin):
     reqs = _mixed_ed25519_batch()
     results, co = _coalesce(reqs)
     _assert_verdict_parity(reqs, results)
     assert co.stats["flushes"] >= 1
-    assert co.stats["batched_sigs"] > 0  # the device batch actually ran
+    assert co.stats["batched_sigs"] > 0
+    # the batch took the device route (its seam stood in, verdicts the
+    # host spec's: the planted bad signature is still refused above)
+    assert sum(device_standin.calls) == co.stats["batched_sigs"]
 
 
 def test_coalesced_verdicts_match_scalar_host_backend():

@@ -33,12 +33,34 @@ def test_sha256_many_np_bulk_random():
         [hashlib.sha256(m).digest() for m in msgs]
 
 
-def test_sha256_many_device_matches_hashlib():
+def _device_sha256_matches_hashlib():
     if not mf.device_ready():
         pytest.skip("no jax device")
     msgs = [bytes([i % 256]) * 65 for i in range(64)]
     assert mf.sha256_many_device(msgs) == \
         [hashlib.sha256(m).digest() for m in msgs]
+
+
+@pytest.mark.slow
+def test_sha256_many_device_matches_hashlib():
+    """The jitted program. ``slow`` because XLA:CPU never ends it: the
+    compile takes seconds, but the EXECUTION time of the fused rounds grows
+    ~2.2x a round (16 rounds 3 ms, 20 rounds 30 ms, 24 rounds 0.8 s,
+    measured PR 26; SHA-256 has 64), at any lane count and block count: it
+    held a worker from the first second of a tier-1 run to the cut at
+    1,470 s. A compiler that ends it (the chip's) runs it; tier-1 holds the
+    same bytes in the twin below."""
+    _device_sha256_matches_hashlib()
+
+
+def test_sha256_many_device_matches_hashlib_op_by_op():
+    """Tier-1 twin of the test above: the same call, the same 64 two-block
+    messages, the same bytes held against hashlib — with jit off, so the
+    jax.numpy rounds run op by op and no program is built."""
+    import jax
+
+    with jax.disable_jit():
+        _device_sha256_matches_hashlib()
 
 
 @pytest.mark.parametrize("n", [0, 1, 2, 3, 4, 5, 7, 8, 9, 64, 65, 127, 128,

@@ -40,7 +40,6 @@ from tendermint_tpu.crypto.breaker import (
 from tendermint_tpu.crypto.ed25519_jax import multidevice as MD
 from tendermint_tpu.crypto.ed25519_jax import verify as V
 from tendermint_tpu.libs.faults import faults
-from tendermint_tpu.libs.metrics import DeviceMetrics, Registry
 from tendermint_tpu.libs.toolbox import load_tool
 
 device_profile = load_tool("device_profile")
@@ -51,16 +50,6 @@ def stub_kernels():
     restore = device_profile.install_stub_kernels(V)
     yield
     restore()
-
-
-@pytest.fixture
-def device_metrics():
-    m = DeviceMetrics(Registry("t"))
-    phases.set_device_metrics(m)
-    phases.reset()
-    yield m
-    phases.set_device_metrics(None)
-    phases.reset()
 
 
 def _workload(n, seed=7, invalid_every=11):

@@ -24,6 +24,12 @@ from tendermint_tpu.types import GenesisDoc, GenesisValidator
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# Block 1 carries the genesis time, and a light client refuses a trusted
+# header older than its trusting period (config.py: 14 days): a constant
+# here is a test that expires. The run's own start, taken once so that a
+# node restarted on the same home sees the same genesis.
+_GENESIS_TIME_NS = time.time_ns()
+
 
 def _mk_node(tmp_path, rpc: bool = True, backend: str = "mem"):
     from tendermint_tpu import crypto
@@ -54,7 +60,7 @@ def _mk_node(tmp_path, rpc: bool = True, backend: str = "mem"):
     params = default_consensus_params()
     params.block.time_iota_ms = 1
     genesis = GenesisDoc(chain_id="rpc-chain",
-                         genesis_time_ns=1_700_000_000_000_000_000,
+                         genesis_time_ns=_GENESIS_TIME_NS,
                          consensus_params=params,
                          validators=[GenesisValidator(pv.get_pub_key(), 10)])
     return Node(cfg, pv, nk, genesis)

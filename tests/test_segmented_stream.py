@@ -2,10 +2,13 @@
 (the flagship 10k-validator optimization: segment i+1's pack+transfer
 overlaps segment i's device compute).
 
-The device kernel itself is covered differentially by test_sparse_verify /
-test_ed25519_jax; here the dispatch step is faked so the orchestration
-(segment sizing, ordering, boundary reassembly, ok-mask merge, pipeline
-depth) is tested without compiling segment-shaped XLA kernels on CPU.
+The sparse and one-call kernels are covered differentially by
+test_sparse_verify / test_ed25519_jax; here the dispatch step is conftest's
+``device_standin``, so the orchestration (segment sizing, ordering, boundary
+reassembly, ok-mask merge, pipeline depth) runs without a build — and the
+last test builds the ONE dense stream program of a tier-1 run (K=2 chunks of
+128 lanes, NBLK 2), the fallback ``_dispatch_stream`` takes for dissimilar
+messages.
 """
 
 import numpy as np
@@ -33,41 +36,21 @@ def test_segment_sizes():
             assert max(sizes) - min(sizes) <= 1  # near-equal
 
 
-class _FakeDev:
-    """Stands in for the device verdict array; np.asarray(fake) works."""
-
-    def __init__(self, arr):
-        self._arr = arr
-
-    def __array__(self, dtype=None, copy=None):
-        return self._arr
-
-
-def test_segmented_reassembly_and_ordering(monkeypatch):
+def test_segmented_reassembly_and_ordering(monkeypatch, device_standin):
     """Verdicts land at the right global offsets regardless of worker
     completion order, and the ok-mask merges per segment."""
-    calls = []
-
-    def fake_dispatch(pks, msgs, sigs, chunk):
-        calls.append(len(pks))
-        # verdict: sig == b"good" + index bytes; ok-mask: pk length valid
-        verd = np.array([s[:4] == b"good" for s in sigs])
-        ok = np.array([len(p) == 32 for p in pks])
-        # pad to whole chunks like the real kernel output
-        k = -(-len(pks) // chunk)
-        verd = np.pad(verd, (0, k * chunk - len(pks)))
-        return _FakeDev(verd), ok
-
-    monkeypatch.setattr(V, "_dispatch_stream", fake_dispatch)
+    # rows are not signatures here: a verdict is "the sig starts with good"
+    device_standin.rule = lambda pk, msg, sig: sig[:4] == b"good"
     n = 1000
     chunk = V.LANE  # 128 -> 8 chunks -> segments [4, 4]
     pks = [b"\x01" * 32] * n
     msgs = [b"m"] * n
-    sigs = [b"good" + bytes([i % 251]) for i in range(n)]
+    sigs = [(b"good" + bytes([i % 251])).ljust(64, b"\x00")
+            for i in range(n)]
     bad = {0, 127, 128, 511, 512, 999}
     for i in bad:
-        sigs[i] = b"bad!" + bytes(1)
-    badpk = {5, 513}
+        sigs[i] = b"bad!".ljust(64, b"\x00")
+    badpk = {5, 513}  # the packer's own ok-mask: a 31-byte key
     for i in badpk:
         pks[i] = b"\x01" * 31
 
@@ -77,62 +60,61 @@ def test_segmented_reassembly_and_ordering(monkeypatch):
     for i in bad | badpk:
         want[i] = False
     np.testing.assert_array_equal(out, want)
+    calls = device_standin.calls
     assert len(calls) == 2 and sum(calls) == n and calls[0] == 512
 
 
-def test_stream_entry_routes_large_batches_to_segments(monkeypatch):
-    seen = []
-
-    def fake_segmented(pks, msgs, sigs, chunk, t_entry=None):
-        seen.append(len(pks))
-        return np.ones(len(pks), bool)
-
-    monkeypatch.setattr(V, "_verify_segmented", fake_segmented)
+def test_stream_entry_routes_large_batches_to_segments(monkeypatch,
+                                                       device_standin):
+    device_standin.rule = lambda pk, msg, sig: True
     monkeypatch.setattr(V, "SEG_MIN_SIGS", 300)
     pks = [b"\x01" * 32] * 400
     msgs = [b"same message"] * 400
     sigs = [b"\x02" * 64] * 400
     out = V.batch_verify_stream(pks, msgs, sigs, chunk=V.LANE)
-    assert seen == [400] and out.all()
+    assert out.all()
+    # 4 chunks -> pipeline segments [2, 2], not one dispatch of 400
+    assert sorted(device_standin.calls) == [144, 256]
 
 
-def test_segmented_worker_exception_propagates(monkeypatch):
-    def boom(pks, msgs, sigs, chunk):
+def test_segmented_worker_exception_propagates(device_standin):
+    def boom(pk, msg, sig):
         raise RuntimeError("device dropped the connection")
 
-    monkeypatch.setattr(V, "_dispatch_stream", boom)
+    device_standin.rule = boom
     with pytest.raises(RuntimeError, match="device dropped"):
         V._verify_segmented([b"\x01" * 32] * 512, [b"m"] * 512,
                             [b"\x02" * 64] * 512, V.LANE)
 
 
-def test_dispatch_stream_dense_fallback_shapes():
-    """_dispatch_stream's dense branch (dissimilar messages) keeps the
-    (K, NBLK, 32, B, LANE) layout contract: verdicts land in row order.
-    Small shapes only — the heavy differential coverage is in
-    test_sparse_verify (CPU) and test_tpu_device (real chip, segmented)."""
-    import pytest
-
+@pytest.mark.parametrize("tampered", [(), (0, 127, 128, 143)],
+                         ids=["all_valid", "tampered_at_chunk_edges"])
+def test_dissimilar_messages_take_the_dense_stream(tampered):
+    """Messages too dissimilar for the sparse wire format fall back to the
+    dense stream kernel, whose (K, NBLK, 32, B, LANE) layout keeps verdicts
+    in row order and equal to the host spec's. The real program, at the
+    one shape tier-1 builds it (144 rows: a second chunk, and padding in
+    it); the segmented shapes run on the chip (test_tpu_device)."""
     pytest.importorskip("cryptography", reason="needs the optional 'cryptography' package (absent in slim containers)")
-    rng = np.random.default_rng(2)
-    pks, msgs, sigs = [], [], []
     from cryptography.hazmat.primitives.asymmetric.ed25519 import (
         Ed25519PrivateKey,
     )
 
-    for i in range(144):  # > one 128-lane chunk -> K=2 stream kernel
+    rng = np.random.default_rng(2)
+    pks, msgs, sigs = [], [], []
+    for i in range(144):
         priv = Ed25519PrivateKey.from_private_bytes(
             bytes(rng.integers(0, 256, 32, dtype=np.uint8)))
         m = bytes(rng.integers(0, 256, 120, dtype=np.uint8))  # dissimilar
         s = priv.sign(m)
-        if i in (0, 127, 128, 143):
+        if i in tampered:
             s = s[:32] + bytes(32)
         pks.append(priv.public_key().public_bytes_raw())
         msgs.append(m)
         sigs.append(s)
     assert V.prepare_sparse_stream(pks, msgs, sigs, 128) is None
-    dev, ok = V._dispatch_stream(pks, msgs, sigs, 128)
-    out = np.asarray(dev).reshape(-1)[:144] & ok
+    out = V.batch_verify_stream(pks, msgs, sigs, chunk=128)
     truth = np.array([host.verify(p, m, s)
                       for p, m, s in zip(pks, msgs, sigs)])
+    assert truth.sum() == 144 - len(tampered)
     np.testing.assert_array_equal(out, truth)

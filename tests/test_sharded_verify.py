@@ -1,7 +1,15 @@
 """Multi-device sharded verification tests (run on the 8 virtual CPU devices
 the conftest pins up). Guards VERDICT round-1 weak #3: multi-chip correctness
 must be exercised by tests, on the batch/sublane axis, with uneven batches.
+
+This file builds the ``full_step`` programs of a tier-1 run: a 2- and an
+8-device mesh (every mesh size is a program of minutes: two is the smallest
+sweep that shows sizes agree), 128 lanes a device, one SHA block — every
+batch here is at most 128 rows of messages under 48 bytes.
 """
+
+import functools
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
@@ -9,6 +17,7 @@ import pytest
 import jax
 
 from tendermint_tpu.crypto import ed25519 as host
+from tendermint_tpu.crypto import phases
 from tendermint_tpu.crypto.ed25519_jax.sharded import batch_verify_sharded, make_mesh
 
 
@@ -23,6 +32,23 @@ def _signed(n, seed=0):
         msgs.append(msg)
         sigs.append(host.sign(sd + pk, msg))
     return pks, msgs, sigs
+
+
+@functools.cache
+def _build_both_meshes():
+    with ThreadPoolExecutor(2) as pool:
+        list(pool.map(lambda d: batch_verify_sharded(*_signed(1),
+                                                     mesh=make_mesh(d)),
+                      (2, 8)))
+
+
+@pytest.fixture(autouse=True)
+def _both_meshes_built():
+    """The two programs of this file, built side by side the first time a
+    test needs either (XLA compiles with the GIL released): one after the
+    other they were the longest pole of a tier-1 run, 290 s + 295 s on a
+    worker while the other five had nothing left to do (PR 26)."""
+    _build_both_meshes()
 
 
 def test_eight_virtual_devices_present():
@@ -46,13 +72,31 @@ def test_sharded_verify_matches_host(n_devices):
 
 
 def test_sharded_mesh_sizes_agree():
-    """Same batch over 2- and 4-device meshes -> identical verdicts."""
+    """Same batch over 2- and 8-device meshes -> identical verdicts."""
     pks, msgs, sigs = _signed(20, seed=9)
     sigs[3] = sigs[3][:-1] + bytes([sigs[3][-1] ^ 0x40])
     v2, t2 = batch_verify_sharded(pks, msgs, sigs, mesh=make_mesh(2))
-    v4, t4 = batch_verify_sharded(pks, msgs, sigs, mesh=make_mesh(4))
-    assert (v2 == v4).all()
-    assert t2 == t4 == int(v2.sum())
+    v8, t8 = batch_verify_sharded(pks, msgs, sigs, mesh=make_mesh(8))
+    assert (v2 == v8).all() and not v2[3] and v2.sum() == 19
+    assert t2 == t8 == int(v2.sum())
+
+
+def test_sharded_mesh_emits_per_device_series(device_metrics):
+    """A sharded dispatch counts every mesh device on the phase plane's
+    per-device series and the record carries the device list."""
+    m = device_metrics
+    pks, msgs, sigs = _signed(16, seed=11)
+    verdict, total = batch_verify_sharded(pks, msgs, sigs, mesh=make_mesh(8))
+    assert verdict.all() and total == 16
+    for i in range(8):
+        assert m.device_dispatch_total.value(f"cpu:{i}") == 1, i
+        assert m.device_inflight.value(f"cpu:{i}") == 0, i
+    rec = phases.recent_segments()[-1]
+    assert rec["device"] == "mesh[8]"
+    assert len(rec["devices"]) == 8
+    assert rec["pack_s"] > 0 and rec["fetch_s"] > 0
+    for phase in ("pack", "dispatch", "fetch"):
+        assert m.segment_phase_seconds.count_value(phase, "sync") == 1
 
 
 def test_make_mesh_too_many_devices_raises():
