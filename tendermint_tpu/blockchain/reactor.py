@@ -362,6 +362,7 @@ class BlockchainReactor(Reactor):
                 # CURRENT set: the run is gated on hash equality, so the
                 # first block's signing set (its previous height's valset)
                 # has identical membership and powers
+                self._prime_sign_bytes(npairs, self.state.chain_id)
                 next_task = loop.run_in_executor(
                     None, self._stage_a, nwindow, npairs, prep.vals_hash,
                     self.state.validators, self.state.validators,
@@ -421,6 +422,26 @@ class BlockchainReactor(Reactor):
                 break  # validator set changes mid-window: verify after advance
             pairs.append((blk, peer_id, nxt, npeer_id))
         return pairs
+
+    @staticmethod
+    def _prime_sign_bytes(pairs, chain_id: str) -> None:
+        """Build the window's sign-bytes rows HERE, on the loop thread,
+        before stage A starts on its worker beside the apply (they memoize
+        on each Commit, so stage A finds them). The vectorised builder is a
+        run of short numpy calls, each of which lets go of the GIL; beside
+        a thread that runs Python (the apply) the worker gets it back only
+        a switch interval later, so a build of 0.5 ms took 42 ms there, the
+        window reached the device after the apply had ended, and the device
+        time no longer hid under it (PERF.md, PR 27). Alone on this thread
+        the window's builds cost ~10 ms at 1,000 validators."""
+        for blk, _p, nxt, _np in pairs:
+            for commit in (blk.last_commit, nxt.last_commit):
+                try:
+                    commit.vote_sign_bytes_all(chain_id)
+                except Exception as e:
+                    # untrusted, not yet validated (no commit, an aggregated
+                    # one, a bad flag): stage A's own handling speaks
+                    logger.debug("sign-bytes not built ahead: %s", e)
 
     # -- stage A: hash + verify (worker thread) -----------------------------
 

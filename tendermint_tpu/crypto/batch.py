@@ -8,6 +8,11 @@ only PubKey.VerifySignature; SURVEY.md north star). Here, callers collect
     bv.add(pub, msg, sig)          # any number of times
     ok_all, per_item = bv.verify() # one TPU kernel launch
 
+A batch whose messages the caller already holds as arrays (a uniform
+commit's sign-bytes, crypto/signcols.SignColumns) comes in whole through
+``add_columns(pks, sigs, columns)`` in place of the ``add`` calls, and goes
+to the device packer as it is: no row is built unless something reads rows.
+
 Backends:
 * "jax"  — the batched TPU/CPU-XLA kernel (ed25519_jax.batch_verify);
 * "host" — scalar loop over PubKey.verify_signature (OpenSSL or pure-Python).
@@ -42,6 +47,9 @@ logger = logging.getLogger("tmtpu.batch")
 DEFAULT_DEVICE_THRESHOLD = 16
 _HOST_SIGS_PER_SEC_ESTIMATE = 7000.0  # OpenSSL verify ~140 us/op
 _calibrated_threshold: Optional[int] = None
+# batch_verify_stream's chunk: up to this many signatures ride the one-call
+# program, which packs rows; above it the stream packs from columns
+STREAM_CHUNK = 2048
 
 
 # routed batches must never lose to the scalar loop: bias the calibrated
@@ -127,6 +135,10 @@ stats = {
     "device_batches": 0, "device_sigs": 0,
     "precomputed_batches": 0, "precomputed_sigs": 0,
     "largest_batch": 0,
+    # batches that came in through add_columns: verified on the device from
+    # their columns, no row built / rows built after all (host route,
+    # device error, precomputed lookup, one-call program)
+    "columnar_batches": 0, "columnar_sigs": 0, "columnar_fallbacks": 0,
     # robustness plane: device attempts that raised (fell back to host) and
     # batches the open circuit breaker kept off the device entirely
     "device_errors": 0, "breaker_rejections": 0,
@@ -143,7 +155,7 @@ def set_crypto_metrics(m) -> None:
     metrics = m
 
 
-def _padded_slots(n: int, chunk: int = 2048) -> int:
+def _padded_slots(n: int, chunk: int = STREAM_CHUNK) -> int:
     """Device slots a batch of n occupies after padding: the stream path
     rounds up to whole chunks, the one-call path to the next power-of-two
     lane bucket (ed25519_jax.verify._pad_to). Used for the pad-waste gauge
@@ -170,13 +182,42 @@ class BatchVerifier:
         # ("votes" live commits, "light" light/fast-sync, "evidence")
         self.plane = plane
         self._pks: List[bytes] = []
-        self._msgs: List[bytes] = []
+        # None while the batch is columnar: its messages are _columns alone
+        self._msgs: Optional[List[bytes]] = []
         self._sigs: List[bytes] = []
         self._non_ed25519: List[Tuple[int, PubKey]] = []
         self._columns = None
 
     def __len__(self) -> int:
         return len(self._pks)
+
+    def add_columns(self, pks: List[bytes], sigs: List[bytes],
+                    columns) -> None:
+        """The columnar way in: a whole batch of ed25519 signatures at once
+        — raw 32-byte keys, signatures, and the messages as
+        crypto/signcols.SignColumns, all aligned — in place of one ``add``
+        a row. The lists are taken as they are (not copied, never written
+        to). Into a batch that already holds rows, or followed by ``add``,
+        it degrades to rows."""
+        if len(pks) != len(sigs) or len(pks) != len(columns):
+            raise ValueError("add_columns: keys, signatures and columns "
+                             "must align")
+        if self._pks:
+            self._rows_form()
+            self._pks.extend(pks)
+            self._msgs.extend(columns.rows())
+            self._sigs.extend(sigs)
+            self._columns = None
+        else:
+            self._pks, self._msgs, self._sigs = pks, None, sigs
+            self._columns = columns
+
+    def _rows_form(self) -> None:
+        """A columnar batch becomes lists of its own with rows built."""
+        if self._msgs is None:
+            self._pks, self._sigs = list(self._pks), list(self._sigs)
+            self._msgs = self._columns.rows()
+            self._columns = None
 
     def set_columns(self, columns) -> None:
         """Columnar sign-bytes (crypto/signcols.SignColumns) aligned 1:1
@@ -187,6 +228,8 @@ class BatchVerifier:
         self._columns = columns
 
     def add(self, pub: PubKey, msg: bytes, sig: bytes) -> None:
+        if self._msgs is None:
+            self._rows_form()
         if not isinstance(pub, Ed25519PubKey):
             # rare key types verify on host; remember position for the verdict
             self._non_ed25519.append((len(self._pks), pub))
@@ -204,15 +247,25 @@ class BatchVerifier:
         n = len(pks)
         if n == 0:
             return True, np.zeros(0, dtype=bool)
+        came_columnar = msgs is None
+
+        def rows() -> List[bytes]:
+            # a columnar batch's messages as bytes, for whatever reads rows
+            nonlocal msgs
+            if msgs is None:
+                msgs = columns.rows()
+            return msgs
 
         stats["largest_batch"] = max(stats["largest_batch"], n)
         pre = precomputed_verdicts.get()
         if pre is not None:
-            hits = [pre.get((pks[i], msgs[i], sigs[i])) for i in range(n)]
+            m = rows()
+            hits = [pre.get((pks[i], m[i], sigs[i])) for i in range(n)]
             if all(h is not None for h in hits):
                 out = np.array(hits, dtype=bool)
                 stats["precomputed_batches"] += 1
                 stats["precomputed_sigs"] += n
+                stats["columnar_fallbacks"] += came_columnar
                 if metrics is not None:
                     metrics.precomputed_hits_total.labels(self.plane).inc()
                 return bool(out.all()), out
@@ -233,10 +286,11 @@ class BatchVerifier:
         non_ed_idx = {i: pk for i, pk in non_ed}
 
         def _host_verify() -> np.ndarray:
+            m = rows()
             res = np.zeros(n, dtype=bool)
             for i in range(n):
                 pub = non_ed_idx.get(i) or Ed25519PubKey(pks[i])
-                res[i] = pub.verify_signature(msgs[i], sigs[i])
+                res[i] = pub.verify_signature(m[i], sigs[i])
             return res
 
         route = "device" if backend == "jax" else "scalar"
@@ -253,27 +307,32 @@ class BatchVerifier:
                     faults.inject("device.batch_verify")
                     from .ed25519_jax import batch_verify_stream
 
-                    ed_pos = [i for i in range(n) if i not in non_ed_idx]
-                    out = np.zeros(n, dtype=bool)
-                    if ed_pos:
-                        # batch_verify_stream == batch_verify below one
-                        # chunk; above, it scans fixed-size chunks inside
-                        # one device execution. The columnar hint only
-                        # survives when it still aligns 1:1 with the rows
-                        # the kernel sees (no non-ed25519 holes)
-                        cols = (columns if columns is not None
-                                and len(ed_pos) == n
-                                and len(columns) == n else None)
-                        ed_out = batch_verify_stream(
-                            [pks[i] for i in ed_pos],
-                            [msgs[i] for i in ed_pos],
-                            [sigs[i] for i in ed_pos],
-                            columns=cols)
-                        out[ed_pos] = ed_out
-                    # rare non-ed25519 keys verify on host, verdicts merged
-                    # by index
-                    for i, pub in non_ed_idx.items():
-                        out[i] = pub.verify_signature(msgs[i], sigs[i])
+                    # batch_verify_stream == batch_verify below one chunk;
+                    # above, it scans fixed-size chunks inside one device
+                    # execution
+                    if not non_ed_idx:
+                        # the lists go down as they are (the stream drops
+                        # a hint that does not align 1:1 with the rows)
+                        if n <= STREAM_CHUNK:
+                            rows()  # the one-call program packs rows
+                        on_device = True
+                        out = batch_verify_stream(pks, msgs, sigs,
+                                                  chunk=STREAM_CHUNK,
+                                                  columns=columns)
+                    else:
+                        # rare non-ed25519 keys verify on host, verdicts
+                        # merged by index; the holes break the hint
+                        ed_pos = [i for i in range(n) if i not in non_ed_idx]
+                        on_device = bool(ed_pos)
+                        out = np.zeros(n, dtype=bool)
+                        if ed_pos:
+                            out[ed_pos] = batch_verify_stream(
+                                [pks[i] for i in ed_pos],
+                                [msgs[i] for i in ed_pos],
+                                [sigs[i] for i in ed_pos],
+                                chunk=STREAM_CHUNK)
+                        for i, pub in non_ed_idx.items():
+                            out[i] = pub.verify_signature(msgs[i], sigs[i])
                 except Exception as e:
                     # a device failure never surfaces to the caller: the
                     # batch re-verifies on host (byte-identical verdicts)
@@ -294,7 +353,7 @@ class BatchVerifier:
                     t0 = time.perf_counter()  # charge only the host verify
                     out = _host_verify()
                 else:
-                    if ed_pos:
+                    if on_device:
                         # only real device evidence closes/holds the
                         # breaker: an all-non-ed25519 batch never touched
                         # the device, and letting it report success would
@@ -302,8 +361,16 @@ class BatchVerifier:
                         device_breaker.record_success()
             else:
                 out = _host_verify()
+            # columnar: the device verified it from its columns, no row built
+            columnar = came_columnar and msgs is None
+            sp.set(columnar=columnar)
         stats["device_batches" if route == "device" else "host_batches"] += 1
         stats["device_sigs" if route == "device" else "host_sigs"] += n
+        if columnar:
+            stats["columnar_batches"] += 1
+            stats["columnar_sigs"] += n
+        else:
+            stats["columnar_fallbacks"] += came_columnar
         if route != "device":
             # scalar-routed (or device-fallback) batches record zero device
             # phases but still count on the device plane's ledger

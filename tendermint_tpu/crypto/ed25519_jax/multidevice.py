@@ -217,8 +217,10 @@ class MultiDeviceStream:
                 device=lane.label, plane=plane, height=height)
             all_recs.append(rec)
             col = columns.slice(a, b) if columns is not None else None
+            # a batch that came as columns alone has no rows to slice
+            seg_msgs = msgs[a:b] if msgs is not None else None
             fut = lane.pool.submit(
-                self._run_lane, lane, rec, pks[a:b], msgs[a:b], sigs[a:b],
+                self._run_lane, lane, rec, pks[a:b], seg_msgs, sigs[a:b],
                 chunk, col)
             return rec, fut
 

@@ -403,8 +403,10 @@ def prepare_sparse_stream(pks, msgs, sigs, chunk: int, columns=None,
     when the messages are too dissimilar for it to pay.
 
     ``columns`` (crypto/signcols.SignColumns, aligned 1:1 with the batch)
-    short-circuits structure discovery; ``device`` commits every input to
-    an explicit device — the multi-device pool's per-lane placement.
+    short-circuits structure discovery, and ``msgs`` may then be None: rows
+    are built only if the columns are too wide for the sparse format;
+    ``device`` commits every input to an explicit device — the multi-device
+    pool's per-lane placement.
 
     Returns (device_args tuple for _verify_sparse_stream_kernel, ok mask).
     """
@@ -413,7 +415,7 @@ def prepare_sparse_stream(pks, msgs, sigs, chunk: int, columns=None,
     if columns is not None and len(columns) == n:
         built = _sparse_from_columns(columns, chunk)
     if built is None:
-        built = _sparse_from_rows(msgs, chunk)
+        built = _sparse_from_rows(_rows_of(msgs, columns), chunk)
     if built is None:
         return None
     templates, cols, diff_vals, mlens, k, pad = built
@@ -575,6 +577,12 @@ def _nblk_bucket(mlen: int) -> int:
     return b
 
 
+def _rows_of(msgs, columns):
+    """The batch's messages as bytes rows: ``msgs``, or, for a batch that
+    came as columns alone (``msgs`` None), the rows built from them."""
+    return columns.rows() if msgs is None else msgs
+
+
 def _group_by_bucket(msgs: Sequence[bytes]):
     groups: dict = {}
     for i, m in enumerate(msgs):
@@ -672,14 +680,15 @@ def _dispatch_stream(pks, msgs, sigs, chunk: int, device=None, columns=None):
 
     ``device`` commits the segment to an explicit device (a multi-device
     pool lane); ``columns`` is the caller's columnar sign-bytes structure
-    (skips the sparse path's join + diff scan)."""
+    (skips the sparse path's join + diff scan; ``msgs`` may then be
+    None)."""
     sparse = prepare_sparse_stream(pks, msgs, sigs, chunk, columns=columns,
                                    device=device)
     if sparse is not None:
         args, ok = sparse
         phases.mark_pack_done()
         return _verify_sparse_stream_kernel(*args), ok
-    args, ok = _pack_stream_dense(pks, msgs, sigs, chunk)
+    args, ok = _pack_stream_dense(pks, _rows_of(msgs, columns), sigs, chunk)
     phases.mark_pack_done()
     if device is not None:
         args = tuple(jax.device_put(a, device) for a in args)
@@ -751,6 +760,9 @@ def _verify_segmented(pks, msgs, sigs, chunk: int,
     sizes = _segment_sizes(-(-n // chunk))
     col_of = ((lambda a, b: columns.slice(a, b)) if columns is not None
               else (lambda a, b: None))
+    # a batch that came as columns alone has no rows to slice
+    msg_of = ((lambda a, b: msgs[a:b]) if msgs is not None
+              else (lambda a, b: None))
     bounds, lo = [], 0
     for s in sizes:
         hi = min(lo + s * chunk, n)
@@ -778,10 +790,10 @@ def _verify_segmented(pks, msgs, sigs, chunk: int,
     # so the pipeline overlap is unaffected
     a0, b0 = bounds[0]
     futs = [_done_future(_run_dispatch(
-        recs[0], pks[a0:b0], msgs[a0:b0], sigs[a0:b0], chunk,
+        recs[0], pks[a0:b0], msg_of(a0, b0), sigs[a0:b0], chunk,
         columns=col_of(a0, b0)))]
     futs += [
-        pool.submit(_run_dispatch, recs[1], pks[a:b], msgs[a:b], sigs[a:b],
+        pool.submit(_run_dispatch, recs[1], pks[a:b], msg_of(a, b), sigs[a:b],
                     chunk, columns=col_of(a, b))
         for a, b in bounds[1:2]
     ]
@@ -793,7 +805,7 @@ def _verify_segmented(pks, msgs, sigs, chunk: int,
             if i + 2 < len(bounds):
                 a2, b2 = bounds[i + 2]
                 futs.append(pool.submit(
-                    _run_dispatch, recs[i + 2], pks[a2:b2], msgs[a2:b2],
+                    _run_dispatch, recs[i + 2], pks[a2:b2], msg_of(a2, b2),
                     sigs[a2:b2], chunk, columns=col_of(a2, b2)))
             arr = np.asarray(dev)
             recs[i].fetched(wait_s=time.perf_counter() - t_wait0)
@@ -842,7 +854,10 @@ def batch_verify_stream(
     per-device circuit breakers, byte-identical verdicts either way.
     ``columns`` (crypto/signcols.SignColumns aligned 1:1 with the batch)
     lets VerifyCommit* callers hand the packer their sign-bytes structure
-    instead of having it re-discovered per segment."""
+    instead of having it re-discovered per segment. With it, ``msgs`` is
+    not read above one chunk and may be None (a batch that came as columns
+    alone): the columns say the one length every row has, and each segment
+    packs from its slice of the arrays."""
     t_entry = time.perf_counter()
     n = len(pks)
     if n == 0:
@@ -850,10 +865,13 @@ def batch_verify_stream(
     if chunk % LANE:
         raise ValueError(f"chunk must be a multiple of {LANE}")
     if columns is not None and len(columns) != n:
+        if msgs is None:
+            raise ValueError("columns do not align with the batch")
         columns = None
     if n <= chunk:
-        return batch_verify(pks, msgs, sigs)
-    groups = _group_by_bucket(msgs)
+        return batch_verify(pks, _rows_of(msgs, columns), sigs)
+    # rows of one length (what columns are) fall into one bucket
+    groups = _group_by_bucket(msgs) if columns is None else ()
     if len(groups) > 1:  # see _nblk_bucket: memory + recompile bound
         out = np.zeros(n, dtype=bool)
         for idxs in groups.values():

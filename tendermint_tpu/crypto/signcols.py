@@ -1,28 +1,27 @@
 """Columnar sign-bytes: the zero-copy vote-pack fast path.
 
 One commit's canonical sign-bytes share every byte except a handful of
-timestamp positions (types/canonical.py vote_sign_bytes_batch builds them
-from cached shared pieces for exactly that reason). The batched device
-verifier then re-DISCOVERS that structure per segment: it joins all rows
-into one (n, mlen) matrix and diff-scans it against per-chunk templates
-(ed25519_jax/verify.prepare_sparse_stream) — O(n*mlen) of memcpy + compare
-per dispatch, a measurable slice of the pack share the bench gates.
-
-:class:`SignColumns` carries the structure the encoder already knows:
+timestamp positions. :class:`SignColumns` carries that structure, so the
+batched device verifier does not have to join all rows into one (n, mlen)
+matrix and diff-scan it per segment
+(ed25519_jax/verify._sparse_from_rows) to find it again:
 
 * ``template`` — one full row's bytes (every row is identical outside
   ``cols``);
 * ``cols``     — the int32 byte positions that vary row to row;
 * ``vals``     — an (n, C) uint8 matrix of each row's bytes at ``cols``.
 
-``types/canonical.vote_sign_bytes_columns_batch`` builds one straight from
-the encoder's cached fragments (no per-row materialization, no diff scan),
-``Commit.vote_sign_bytes_columns`` memoizes it per chain_id, and the
-VerifyCommit* callers hand it to BatchVerifier, which threads it down to
-``prepare_sparse_stream`` — the sparse wire format is assembled by slicing
-these arrays instead of re-deriving them. Row reconstruction is
-byte-identical to ``vote_sign_bytes_all`` (differentially tested), so
-accept/reject verdicts cannot change.
+``types/canonical.vote_sign_bytes_table`` encodes a whole commit as byte
+matrices (numpy varints, no Python per row) and hands out both forms: rows
+as ``bytes`` and, for rows of one length class, these columns. The
+VerifyCommit* entries give a uniform commit to ``BatchVerifier.add_columns``
+WHOLE, in place of rows: pubkeys, signatures and columns go down to
+``prepare_sparse_stream``, which slices the sparse wire format out of these
+arrays, and a row is built (``rows()``) only where something reads rows:
+the host route, a re-verify after a device error, a precomputed-verdict
+lookup, the one-call program. Row reconstruction is byte-identical to
+``vote_sign_bytes`` (differentially tested), so accept/reject verdicts
+cannot change.
 
 numpy-only and jax-free: types/ code builds these without dragging the
 device stack into encode paths.
@@ -90,19 +89,22 @@ class SignColumns:
                            self.vals[np.asarray(idxs, dtype=np.intp)])
 
     def rows(self) -> list:
-        """Materialized bytes rows (host fallback; O(n*mlen))."""
-        n = len(self)
-        arr = np.broadcast_to(self.template, (n, self.mlen)).copy()
+        """Materialized bytes rows (host fallback; O(n*mlen)): one matrix,
+        one ``tobytes``, one slice a row."""
+        n, ml = len(self), self.mlen
+        arr = np.empty((n, ml), dtype=np.uint8)
+        arr[:] = self.template
         arr[:, self.cols] = self.vals
-        return [r.tobytes() for r in arr]
+        buf = arr.tobytes()
+        return [buf[o:o + ml] for o in range(0, n * ml, ml)]
 
 
 def sign_columns_from_rows(rows: Sequence[bytes]) -> "Optional[SignColumns]":
     """Tx-side SignColumns analogue (mempool/ingest.py micro-batches).
 
-    Votes get their columns from the encoder's cached fragments
-    (``vote_sign_bytes_columns_batch``); tx sign-bytes have no encoder
-    cache, but a micro-batch of same-shape envelopes still shares most
+    Votes get their columns from the encoder's own matrix
+    (``vote_sign_bytes_table``); tx sign-bytes have no such encoder, but a
+    micro-batch of same-shape envelopes still shares most
     bytes (magic, fee/nonce prefixes, payload padding). One vectorized
     diff-scan at PACK time — on the intake path, once per micro-batch —
     yields the same zero-copy structure, instead of the verifier

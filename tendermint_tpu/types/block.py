@@ -3,24 +3,25 @@
 Header.Hash merkle-izes the 14 proto-encoded fields (block.go:440-475);
 Commit.Hash merkle-izes CommitSig proto encodings (block.go:894-912);
 Commit.vote_sign_bytes rebuilds each validator's canonical vote sign-bytes
-(block.go:784-810) — the per-index payload of the batched verifier.
+(block.go:784-810) — the per-index payload of the batched verifier;
+Commit.vote_sign_bytes_all / _columns give all of them at once, as rows or
+as arrays, from one vectorised encode (canonical.vote_sign_bytes_table).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from operator import attrgetter
 from typing import List, Optional
+
+import numpy as np
 
 from .. import crypto
 from ..crypto import merkle, schemes
 from ..libs import protowire as pw
 from ..libs.bits import BitArray
 from .basic import BlockID, BlockIDFlag, PartSetHeader, SignedMsgType, ZERO_TIME_NS
-from .canonical import (
-    vote_sign_bytes,
-    vote_sign_bytes_batch,
-    vote_sign_bytes_columns_batch,
-)
+from .canonical import vote_sign_bytes, vote_sign_bytes_table
 from .tx import txs_hash
 from .vote import MAX_SIGNATURE_SIZE, Vote
 
@@ -285,8 +286,7 @@ class CommitSig:
         return cs
 
 
-#: memo sentinel: vote_sign_bytes_columns legitimately caches None
-_NO_COLUMNS = object()
+_FLAG_OF = attrgetter("block_id_flag")
 
 
 @dataclass
@@ -325,56 +325,67 @@ class Commit:
             ts,
         )
 
-    def vote_sign_bytes_all(self, chain_id: str) -> List[bytes]:
-        """Every validator's canonical sign-bytes in one pass, memoized per
-        (chain_id, zero-ts flag). Batched commit verification needs all rows
-        anyway, and the shared-field assembly
-        (canonical.vote_sign_bytes_batch) plus the memo cut the dominant
-        host-side cost of the device verify path. Commits are immutable once
-        built, so the memo only invalidates if the chain's scheme flips
-        zero_precommit_ts under us — hence the flag in the key."""
+    def block_id_flags(self):
+        """Every row's BlockIDFlag as a uint8 array, read from the rows now
+        (nothing kept): how the VerifyCommit* entries pick their candidates
+        without a Python loop over the rows."""
+        try:
+            raw = bytes(map(_FLAG_OF, self.signatures))
+        except ValueError:  # a flag no byte holds: the row's own error
+            for cs in self.signatures:
+                cs.block_id(self.block_id)
+            raise
+        return np.frombuffer(raw, dtype=np.uint8)
+
+    def _sign_bytes_table(self, chain_id: str):
+        """The whole commit's sign-bytes as canonical.VoteSignBytes, memoized
+        per (chain_id, zero-ts flag): one vectorised encode
+        (canonical.vote_sign_bytes_table) serves rows and columns. Commits
+        are immutable once built, so the memo only invalidates if the
+        chain's scheme flips zero_precommit_ts under us — hence the flag in
+        the key."""
         zero = schemes.for_chain(chain_id).zero_precommit_ts
         cache = self.__dict__.setdefault("_sb_cache", {})
         hit = cache.get((chain_id, zero))
         if hit is None:
-            hit = vote_sign_bytes_batch(
+            sigs = self.signatures
+            flags = self.block_id_flags()
+            nil = flags != BlockIDFlag.COMMIT
+            unknown = nil & (flags != BlockIDFlag.ABSENT) \
+                & (flags != BlockIDFlag.NIL)
+            if unknown.any():  # raises, as the per-row encoder does
+                sigs[int(np.argmax(unknown))].block_id(self.block_id)
+            hit = vote_sign_bytes_table(
                 chain_id,
                 SignedMsgType.PRECOMMIT,
                 self.height,
                 self.round,
-                [cs.block_id(self.block_id) for cs in self.signatures],
-                [schemes.AGG_ZERO_TS_NS if zero else cs.timestamp_ns
-                 for cs in self.signatures],
+                [self.block_id, BlockID()],
+                nil.astype(np.intp) if nil.any() else None,
+                [schemes.AGG_ZERO_TS_NS] * len(sigs) if zero
+                else [cs.timestamp_ns for cs in sigs],
             )
             cache[(chain_id, zero)] = hit
         return hit
 
-    def vote_sign_bytes_columns(self, chain_id: str):
-        """Columnar sign-bytes (crypto.signcols.SignColumns) for the whole
-        commit, memoized per (chain_id, scheme) like vote_sign_bytes_all — or
-        None when the rows are not structurally uniform (nil votes mixed in,
-        ragged timestamp encodings) or when the chain's scheme is not
-        ed25519: the columns feed the ed25519 device pack path exclusively,
-        and a memo keyed on chain_id alone would keep serving stale ed25519
-        columns after the chain registers a different scheme. Row i
-        reconstructs byte-identically to vote_sign_bytes_all(chain_id)[i]."""
-        sch = schemes.for_chain(chain_id)
-        if sch.scheme != schemes.SCHEME_ED25519:
+    def vote_sign_bytes_all(self, chain_id: str) -> List[bytes]:
+        """Every validator's canonical sign-bytes as ``bytes`` rows, cut
+        from the memoized table's matrices (block.go:807 per index). The
+        callers that read rows: the batched window functions, the reactor,
+        the one-call program below one chunk, host fallbacks."""
+        return self._sign_bytes_table(chain_id).rows()
+
+    def vote_sign_bytes_columns(self, chain_id: str, idxs=None):
+        """Columnar sign-bytes (crypto.signcols.SignColumns) of the rows at
+        ``idxs`` (the whole commit when None, memoized on the table) — or
+        None when those rows are not structurally uniform (nil votes mixed
+        in, ragged timestamp encodings) or when the chain's scheme is not
+        ed25519: the columns feed the ed25519 device pack path exclusively.
+        Row i reconstructs byte-identically to
+        vote_sign_bytes_all(chain_id)[idxs[i]]."""
+        if schemes.for_chain(chain_id).scheme != schemes.SCHEME_ED25519:
             return None
-        cache = self.__dict__.setdefault("_sbc_cache", {})
-        key = (chain_id, sch.scheme, sch.zero_precommit_ts)
-        hit = cache.get(key, _NO_COLUMNS)
-        if hit is _NO_COLUMNS:
-            hit = vote_sign_bytes_columns_batch(
-                chain_id,
-                SignedMsgType.PRECOMMIT,
-                self.height,
-                self.round,
-                [cs.block_id(self.block_id) for cs in self.signatures],
-                [cs.timestamp_ns for cs in self.signatures],
-            )
-            cache[key] = hit
-        return hit
+        return self._sign_bytes_table(chain_id).columns(idxs)
 
     def size(self) -> int:
         return len(self.signatures)
@@ -487,7 +498,7 @@ class AggregatedCommit(Commit):
     def vote_sign_bytes_all(self, chain_id: str):
         raise TypeError("aggregated commit has no per-validator sign-bytes")
 
-    def vote_sign_bytes_columns(self, chain_id: str):
+    def vote_sign_bytes_columns(self, chain_id: str, idxs=None):
         return None
 
     def hash(self) -> bytes:

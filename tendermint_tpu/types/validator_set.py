@@ -7,14 +7,19 @@ are verified: all candidate signatures are collected into one BatchVerifier
 call (TPU Pallas kernel batch) and the scalar loop's decisions — including
 VerifyCommitLight's early exit at 2/3 — are replayed over the batch verdicts,
 so accept/reject and error selection are byte-identical to the reference while
-the crypto runs as one device batch instead of N host calls.
+the crypto runs as one device batch instead of N host calls. Candidates,
+verdicts and the tally are arrays (flags, the first False, a cumulative sum of
+powers): no Python runs per row of a large commit.
 """
 
 from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence, Tuple
 
-from ..crypto.batch import BatchVerifier
+import numpy as np
+
+from ..crypto import Ed25519PubKey
+from ..crypto.batch import STREAM_CHUNK, BatchVerifier
 from .basic import BlockID, BlockIDFlag
 from .errors import (
     ErrInvalidCommitHeight,
@@ -58,6 +63,31 @@ def _observe_aggregated_wire_size(commit) -> None:
 def _by_voting_power(v: Validator):
     """Sort key: power desc, address asc (reference types/validator.go ValidatorsByVotingPower)."""
     return (-v.voting_power, v.address)
+
+
+def _raise_first_wrong(commit, idxs: np.ndarray, ok: np.ndarray) -> None:
+    """ErrWrongSignature for the first candidate row whose verdict is False
+    (candidate order is row order), as the scalar loop meets it."""
+    bad = np.flatnonzero(~ok)
+    if bad.shape[0]:
+        idx = int(idxs[bad[0]])
+        raise ErrWrongSignature(idx, commit.signatures[idx].signature)
+
+
+def _replay_light(commit, idxs: np.ndarray, ok: np.ndarray,
+                  powers: np.ndarray, needed: int) -> None:
+    """VerifyCommitLight's loop over candidate rows ``idxs`` (verdicts
+    ``ok``, voting ``powers``): each row's signature is checked, then its
+    power tallied, and the loop returns where the tally first exceeds
+    ``needed``. A wrong signature up to that row raises; one after it is
+    never looked at."""
+    cum = np.cumsum(powers)
+    over = np.flatnonzero(cum > needed)
+    read = int(over[0]) + 1 if over.shape[0] else len(idxs)
+    _raise_first_wrong(commit, idxs[:read], ok[:read])
+    if not over.shape[0]:
+        raise ErrNotEnoughVotingPowerSigned(
+            int(cum[-1]) if len(idxs) else 0, needed)
 
 
 class ValidatorSet:
@@ -119,16 +149,17 @@ class ValidatorSet:
         vs.validators = [v.copy() for v in self.validators]
         vs.proposer = self.proposer
         vs._total_voting_power = self._total_voting_power
-        # membership and powers are identical, so the merkle hash carries
-        # over (priorities are not part of bytes_for_hash); re-keyed to the
-        # copy's own list + mutation count so later structural mutations
-        # invalidate normally
-        cache = self.__dict__.get("_hash_cache")
-        if cache is not None and cache[0] is self.validators \
-                and cache[1] == self._mutations \
-                and cache[2] == len(self.validators):
-            vs.__dict__["_hash_cache"] = (vs.validators, vs._mutations,
-                                          len(vs.validators), cache[3])
+        # membership, keys and powers are identical, so the merkle hash and
+        # the verify arrays carry over (priorities are part of neither);
+        # re-keyed to the copy's own list + mutation count so later
+        # structural mutations invalidate normally
+        for name in ("_hash_cache", "_verify_cache"):
+            cache = self.__dict__.get(name)
+            if cache is not None and cache[0] is self.validators \
+                    and cache[1] == self._mutations \
+                    and cache[2] == len(self.validators):
+                vs.__dict__[name] = (vs.validators, vs._mutations,
+                                     len(vs.validators), cache[3])
         return vs
 
     def _bump_mutations(self) -> None:
@@ -137,6 +168,19 @@ class ValidatorSet:
         mutation that preserves list identity and length still invalidates."""
         self._mutations += 1
 
+    def _memo(self, name: str, build):
+        """``build()``'s result, kept in ``__dict__[name]`` for as long as
+        the validators list is the same object of the same length and no
+        structural mutator has bumped ``_mutations``."""
+        cache = self.__dict__.get(name)
+        if (cache is None or cache[0] is not self.validators
+                or cache[1] != self._mutations
+                or cache[2] != len(self.validators)):
+            cache = (self.validators, self._mutations, len(self.validators),
+                     build())
+            self.__dict__[name] = cache
+        return cache[3]
+
     def _addr_index(self) -> dict:
         """address -> index, rebuilt whenever the validators list object is
         replaced, resized, or a structural mutator bumps ``_mutations``
@@ -144,17 +188,34 @@ class ValidatorSet:
         order, so the cache stays valid across IncrementProposerPriority).
         At light-client/commit-verification scale the linear scan was the
         single hottest host-side cost (1000-validator sets x 32k lookups)."""
-        cache = self.__dict__.get("_addr_cache")
-        if (cache is None or cache[0] is not self.validators
-                or cache[1] != self._mutations
-                or cache[2] != len(self.validators)):
+        def build() -> dict:
             idx: dict = {}
             for i, v in enumerate(self.validators):
                 idx.setdefault(v.address, i)  # first match wins, like the scan
-            cache = (self.validators, self._mutations, len(self.validators),
-                     idx)
-            self.__dict__["_addr_cache"] = cache
-        return cache[3]
+            return idx
+
+        return self._memo("_addr_cache", build)
+
+    def _verify_arrays(self):
+        """``(pubkey bytes per validator | None, voting powers array)`` for
+        commit verification, under _addr_index's rule (list identity,
+        ``_mutations``, length): the one thing a verify call keeps for the
+        next, and it derives from the set alone. The key list is None when
+        a key is not ed25519 (the columnar way into the verifier takes raw
+        ed25519 keys). Powers are int64 — every partial sum fits while they
+        are non-negative, total_voting_power() holding the total to
+        MAX_TOTAL_VOTING_POWER = 2^63 / 8 — and Python ints in an object
+        array otherwise, exact either way."""
+        def build():
+            vals = self.validators
+            pks = ([v.pub_key.bytes() for v in vals]
+                   if all(isinstance(v.pub_key, Ed25519PubKey) for v in vals)
+                   else None)
+            powers = [v.voting_power for v in vals]
+            fits = all(0 <= p <= MAX_TOTAL_VOTING_POWER for p in powers)
+            return pks, np.array(powers, dtype=np.int64 if fits else object)
+
+        return self._memo("_verify_cache", build)
 
     def has_address(self, address: bytes) -> bool:
         return address in self._addr_index()
@@ -196,17 +257,13 @@ class ValidatorSet:
         1000-validator sets per block, and copy() propagates the memo, so
         steady-state fast sync pays the merkle pass only when membership
         actually changes."""
-        cache = self.__dict__.get("_hash_cache")
-        if (cache is None or cache[0] is not self.validators
-                or cache[1] != self._mutations
-                or cache[2] != len(self.validators)):
+        def build() -> bytes:
             from ..crypto import merkle
 
-            h = merkle.hash_from_byte_slices(
+            return merkle.hash_from_byte_slices(
                 [v.bytes_for_hash() for v in self.validators])
-            cache = (self.validators, self._mutations, len(self.validators), h)
-            self.__dict__["_hash_cache"] = cache
-        return cache[3]
+
+        return self._memo("_hash_cache", build)
 
     def validate_basic(self) -> None:
         if self.is_nil_or_empty():
@@ -379,8 +436,10 @@ class ValidatorSet:
     # -- commit verification (validator_set.go:667-821) --------------------
     #
     # Each variant: one batched device call over the candidate signatures,
-    # then a sequential replay of the reference's scalar loop over the
-    # verdicts so error precedence and early exits match exactly.
+    # then the reference's scalar loop over the verdicts, computed on
+    # arrays, so error precedence and early exits match exactly: the loop
+    # stops at its first wrong signature or where the tally crosses, and
+    # whichever row comes first decides.
 
     def verify_commit(self, chain_id: str, block_id: BlockID, height: int, commit) -> None:
         """All signatures checked; absent skipped; nil votes verified but not
@@ -388,16 +447,13 @@ class ValidatorSet:
         self._check_commit_shape(commit, height, block_id)
         if _is_aggregated(commit):
             return self._verify_aggregated(chain_id, commit, mode="full")
-        idxs = [i for i, cs in enumerate(commit.signatures) if not cs.absent()]
+        flags = commit.block_id_flags()
+        idxs = np.flatnonzero(flags != BlockIDFlag.ABSENT)
         ok = self._batch_verify(chain_id, commit, idxs)
-        tallied = 0
         needed = self.total_voting_power() * 2 // 3
-        for pos, idx in enumerate(idxs):
-            cs = commit.signatures[idx]
-            if not ok[pos]:
-                raise ErrWrongSignature(idx, cs.signature)
-            if cs.for_block():
-                tallied += self.validators[idx].voting_power
+        _raise_first_wrong(commit, idxs, ok)
+        tallied = int(self._verify_arrays()[1][flags == BlockIDFlag.COMMIT]
+                      .sum())
         if tallied <= needed:
             raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
@@ -409,17 +465,11 @@ class ValidatorSet:
             # one pairing over the whole bitmap: there is no cheaper
             # early-exit prefix to stop at
             return self._verify_aggregated(chain_id, commit, mode="light")
-        idxs = [i for i, cs in enumerate(commit.signatures) if cs.for_block()]
+        idxs = np.flatnonzero(commit.block_id_flags() == BlockIDFlag.COMMIT)
         ok = self._batch_verify(chain_id, commit, idxs, plane="light")
-        tallied = 0
         needed = self.total_voting_power() * 2 // 3
-        for pos, idx in enumerate(idxs):
-            if not ok[pos]:
-                raise ErrWrongSignature(idx, commit.signatures[idx].signature)
-            tallied += self.validators[idx].voting_power
-            if tallied > needed:
-                return
-        raise ErrNotEnoughVotingPowerSigned(tallied, needed)
+        _replay_light(commit, idxs, ok, self._verify_arrays()[1][idxs],
+                      needed)
 
     def verify_commit_light_trusting(self, chain_id: str, commit,
                                      trust_level: Fraction,
@@ -446,29 +496,44 @@ class ValidatorSet:
             return self._verify_aggregated_trusting(
                 chain_id, commit, needed, commit_vals)
 
-        # Candidates: for-block sigs whose address is in the trusted set.
-        cand: List[Tuple[int, int, Validator]] = []  # (commit idx, val idx, val)
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is not None:
-                cand.append((idx, val_idx, val))
-        ok = self._batch_verify(chain_id, commit, [c[0] for c in cand],
-                                pubkeys=[c[2].pub_key for c in cand],
+        idxs, val_idxs = self._trusting_candidates(commit)
+        ok = self._batch_verify(chain_id, commit, idxs, key_idxs=val_idxs,
                                 plane="light")
-        tallied = 0
-        seen = {}
-        for pos, (idx, val_idx, val) in enumerate(cand):
-            if val_idx in seen:
-                raise ValueError(f"double vote from {val}: ({seen[val_idx]} and {idx})")
-            seen[val_idx] = idx
-            if not ok[pos]:
-                raise ErrWrongSignature(idx, commit.signatures[idx].signature)
-            tallied += val.voting_power
-            if tallied > needed:
-                return
-        raise ErrNotEnoughVotingPowerSigned(tallied, needed)
+        self._replay_trusting(commit, idxs, val_idxs, ok, needed)
+
+    def _trusting_candidates(self, commit):
+        """``(commit rows, validator index of each)``: the for-block rows
+        whose address this (trusted) set knows, in row order."""
+        addr_idx = self._addr_index()
+        val_of = np.fromiter(
+            (addr_idx.get(cs.validator_address, -1)
+             for cs in commit.signatures),
+            dtype=np.intp, count=len(commit.signatures))
+        idxs = np.flatnonzero(
+            (commit.block_id_flags() == BlockIDFlag.COMMIT) & (val_of >= 0))
+        return idxs, val_of[idxs]
+
+    def _replay_trusting(self, commit, idxs: np.ndarray, val_idxs: np.ndarray,
+                         ok: np.ndarray, needed: int) -> None:
+        """VerifyCommitLightTrusting's loop over its candidates. It refuses
+        a validator's second vote before it looks at that row's signature:
+        read the rows before the first repeat as the light rule does, then
+        let the repeat speak if the loop got that far."""
+        _, first_at = np.unique(val_idxs, return_index=True)
+        repeat = np.ones(len(idxs), dtype=bool)
+        repeat[first_at] = False
+        stop = int(np.argmax(repeat)) if repeat.any() else len(idxs)
+        try:
+            _replay_light(commit, idxs[:stop], ok[:stop],
+                          self._verify_arrays()[1][val_idxs[:stop]], needed)
+        except ErrNotEnoughVotingPowerSigned:
+            if stop == len(idxs):
+                raise
+            val_idx = int(val_idxs[stop])
+            earlier = int(idxs[np.flatnonzero(val_idxs[:stop] == val_idx)[0]])
+            raise ValueError(
+                f"double vote from {self.validators[val_idx]}: "
+                f"({earlier} and {int(idxs[stop])})") from None
 
     def _check_commit_shape(self, commit, height: int, block_id: BlockID) -> None:
         # commit.size(): CommitSig rows for plain commits, signer-bitmap
@@ -535,30 +600,46 @@ class ValidatorSet:
                 return
         raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
-    def _batch_verify(self, chain_id: str, commit, idxs: Sequence[int],
-                      pubkeys: Optional[Sequence] = None,
-                      plane: str = "votes") -> List[bool]:
-        if not idxs:
-            return []
+    def _batch_verify(self, chain_id: str, commit, idxs: np.ndarray,
+                      key_idxs: Optional[np.ndarray] = None,
+                      plane: str = "votes") -> np.ndarray:
+        """Verdicts of the commit rows ``idxs``, each against the key of
+        validator ``key_idxs[pos]`` (the same index where None), in one
+        batch. How the batch is built follows from what the commit shows:
+
+        * more than a stream chunk of candidates, all of one sign-bytes
+          length class (flags, timestamp varint widths), every key of the
+          set ed25519: keys, signatures and the sign-bytes COLUMNS go in
+          whole (BatchVerifier.add_columns) and no row is built;
+        * anything else: rows, from the same vectorised builder
+          (Commit.vote_sign_bytes_all) — up to a chunk the one-call
+          program packs rows anyway;
+        * up to 32 candidates: the per-index encoder, nothing memoized.
+        """
+        n = len(idxs)
+        if n == 0:
+            return np.zeros(0, dtype=bool)
         bv = BatchVerifier(plane=plane)
-        # amortized sign-bytes: one shared-field encode for the whole commit
-        # instead of len(idxs) canonical encodes (the host-side cost floor)
-        sb = (commit.vote_sign_bytes_all(chain_id) if len(idxs) > 32
-              else None)
-        for pos, idx in enumerate(idxs):
-            pk = pubkeys[pos] if pubkeys is not None else self.validators[idx].pub_key
-            msg = sb[idx] if sb is not None else commit.vote_sign_bytes(chain_id, idx)
-            bv.add(pk, msg, commit.signatures[idx].signature)
-        if sb is not None:
-            # columnar fast path: hand the device packer the commit's
-            # sign-bytes structure (template + varying timestamp columns)
-            # so it skips the per-segment join + diff re-discovery. None
-            # for structurally non-uniform commits (nil votes mixed in).
-            cols = commit.vote_sign_bytes_columns(chain_id)
-            if cols is not None:
-                bv.set_columns(cols.subset(idxs))
-        _, per_item = bv.verify()
-        return [bool(b) for b in per_item]
+        sigs = commit.signatures
+        rows_at = idxs.tolist()
+        keys_at = rows_at if key_idxs is None else key_idxs.tolist()
+        pks = self._verify_arrays()[0]
+        cols = (commit.vote_sign_bytes_columns(chain_id, idxs)
+                if n > STREAM_CHUNK and pks is not None else None)
+        if cols is not None:
+            if key_idxs is not None or n != len(pks):
+                pks = [pks[k] for k in keys_at]
+            bv.add_columns(pks, [sigs[i].signature for i in rows_at], cols)
+        else:
+            # amortized sign-bytes: one shared-field encode for the whole
+            # commit instead of n canonical encodes
+            sb = commit.vote_sign_bytes_all(chain_id) if n > 32 else None
+            vals = self.validators
+            for idx, k in zip(rows_at, keys_at):
+                msg = (sb[idx] if sb is not None
+                       else commit.vote_sign_bytes(chain_id, idx))
+                bv.add(vals[k].pub_key, msg, sigs[idx].signature)
+        return np.asarray(bv.verify()[1], dtype=bool)
 
     # -- proto ------------------------------------------------------------
 
@@ -604,7 +685,7 @@ def verify_commit_light_batched(
     Entries: (val_set, chain_id, block_id, height, commit).
     """
     bv = BatchVerifier(plane="light")
-    slices: List[Tuple[int, List[int]]] = []  # (batch offset, candidate idxs)
+    slices: List[Tuple[int, Sequence[int]]] = []  # (batch offset, candidate idxs)
     shape_errors: List[Optional[Exception]] = []
     agg_done: dict = {}  # entry position -> result for aggregated commits
     off = 0
@@ -627,14 +708,14 @@ def verify_commit_light_batched(
             slices.append((off, []))
             continue
         shape_errors.append(None)
-        idxs = [i for i, cs in enumerate(commit.signatures) if cs.for_block()]
+        idxs = np.flatnonzero(commit.block_id_flags() == BlockIDFlag.COMMIT)
         sb = commit.vote_sign_bytes_all(chain_id)
         vals = val_set.validators
-        for idx in idxs:
+        for idx in idxs.tolist():
             bv.add(vals[idx].pub_key, sb[idx], commit.signatures[idx].signature)
         slices.append((off, idxs))
         off += len(idxs)
-    _, per_item = bv.verify()
+    per_item = np.asarray(bv.verify()[1], dtype=bool)
 
     results: List[Optional[Exception]] = []
     for pos_e, (entry, shape_err, (start, idxs)) in enumerate(
@@ -645,20 +726,14 @@ def verify_commit_light_batched(
         if shape_err is not None:
             results.append(shape_err)
             continue
-        val_set, chain_id, block_id, height, commit = entry
-        tallied = 0
-        needed = val_set.total_voting_power() * 2 // 3
-        err: Optional[Exception] = None
-        for pos, idx in enumerate(idxs):
-            if not per_item[start + pos]:
-                err = ErrWrongSignature(idx, commit.signatures[idx].signature)
-                break
-            tallied += val_set.validators[idx].voting_power
-            if tallied > needed:
-                break
-        else:
-            err = ErrNotEnoughVotingPowerSigned(tallied, needed)
-        results.append(err)
+        val_set, commit = entry[0], entry[4]
+        try:
+            _replay_light(commit, idxs, per_item[start:start + len(idxs)],
+                          val_set._verify_arrays()[1][idxs],
+                          val_set.total_voting_power() * 2 // 3)
+            results.append(None)
+        except (ErrWrongSignature, ErrNotEnoughVotingPowerSigned) as e:
+            results.append(e)
     return results
 
 
@@ -681,7 +756,7 @@ def verify_commit_light_trusting_batched(
     exception verify_commit_light_trusting would have raised.
     """
     bv = BatchVerifier(plane="light")
-    slices: List[Tuple[int, List[Tuple[int, int, Validator]]]] = []
+    slices: List[Tuple[int, tuple]] = []  # (batch offset, candidates)
     pre_errors: List[Optional[Exception]] = []
     needed_list: List[int] = []
     agg_done: dict = {}  # entry position -> result for aggregated commits
@@ -719,20 +794,14 @@ def verify_commit_light_trusting_batched(
         pre_errors.append(None)
         needed_list.append(total_mul // denom)
         sb = commit.vote_sign_bytes_all(chain_id)
-        addr_idx = val_set._addr_index()
+        idxs, val_idxs = val_set._trusting_candidates(commit)
         vals = val_set.validators
-        cand: List[Tuple[int, int, Validator]] = []
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx = addr_idx.get(cs.validator_address)
-            if val_idx is not None:
-                val = vals[val_idx]
-                cand.append((idx, val_idx, val))
-                bv.add(val.pub_key, sb[idx], cs.signature)
-        slices.append((off, cand))
-        off += len(cand)
-    _, per_item = bv.verify()
+        for idx, val_idx in zip(idxs.tolist(), val_idxs.tolist()):
+            bv.add(vals[val_idx].pub_key, sb[idx],
+                   commit.signatures[idx].signature)
+        slices.append((off, (idxs, val_idxs)))
+        off += len(idxs)
+    per_item = np.asarray(bv.verify()[1], dtype=bool)
 
     results: List[Optional[Exception]] = []
     for pos_e, (entry, pre_err, (start, cand), needed) in enumerate(zip(
@@ -743,25 +812,15 @@ def verify_commit_light_trusting_batched(
         if pre_err is not None:
             results.append(pre_err)
             continue
-        commit = entry[2]
-        tallied = 0
-        seen: dict = {}
-        err: Optional[Exception] = None
-        for pos, (idx, val_idx, val) in enumerate(cand):
-            if val_idx in seen:
-                err = ValueError(
-                    f"double vote from {val}: ({seen[val_idx]} and {idx})")
-                break
-            seen[val_idx] = idx
-            if not per_item[start + pos]:
-                err = ErrWrongSignature(idx, commit.signatures[idx].signature)
-                break
-            tallied += val.voting_power
-            if tallied > needed:
-                break
-        else:
-            err = ErrNotEnoughVotingPowerSigned(tallied, needed)
-        results.append(err)
+        idxs, val_idxs = cand
+        try:
+            entry[0]._replay_trusting(
+                entry[2], idxs, val_idxs,
+                per_item[start:start + len(idxs)], needed)
+            results.append(None)
+        except (ErrWrongSignature, ErrNotEnoughVotingPowerSigned,
+                ValueError) as e:
+            results.append(e)
     return results
 
 

@@ -132,6 +132,7 @@ class DeviceStandIn:
         from tendermint_tpu.crypto.ed25519_jax import verify as V
 
         self.calls.append(len(pks))
+        msgs = V._rows_of(msgs, columns)  # a columnar segment has no rows
         ok = V._sig_pk_arrays(pks, sigs)[3]
         time.sleep(self.pack_s)
         phases.mark_pack_done()  # the stamp _dispatch_stream places

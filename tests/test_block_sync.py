@@ -347,6 +347,68 @@ def test_window_precompute_covers_both_planes(one_val_genesis, monkeypatch):
     conns2.stop()
 
 
+def test_prepared_ahead_windows_find_their_sign_bytes_built(one_val_genesis,
+                                                            monkeypatch):
+    """Stage A of a prepared-ahead window runs on a worker beside the apply;
+    the vectorised sign-bytes builder (numpy, lets go of the GIL call by
+    call) crawls there, so the loop thread builds the window's rows before
+    it starts the worker (PR 27). Only the first, inline window, whose
+    stage A runs with the loop waiting, builds on the worker."""
+    import threading
+
+    import tendermint_tpu.blockchain.reactor as R
+    import tendermint_tpu.types.block as B
+
+    monkeypatch.setenv("TMTPU_BATCH_BACKEND", "host")
+    pv, genesis = one_val_genesis
+    n = 2 * R.VERIFY_WINDOW + 6
+    _state, _ss, src_store, conns, _app = build_chain(n + 2, pv, genesis)
+    conns2 = AppConns(local_client_creator(KVStoreApplication()))
+    conns2.start()
+    state2 = state_from_genesis(genesis)
+    ss2 = StateStore(MemDB())
+    ss2.save(state2)
+    bs2 = BlockStore(MemDB())
+    ex2 = BlockExecutor(ss2, conns2.consensus, NoOpMempool(),
+                        EmptyEvidencePool(), bs2)
+    reactor = R.BlockchainReactor(state2, ex2, bs2, fast_sync=True)
+    reactor.pool = R.BlockPool(1)
+    reactor.pool.set_peer_range("src", 1, n + 1)
+
+    built = []  # (commit height, on the loop thread?)
+    real = B.vote_sign_bytes_table
+
+    def recording(chain_id, vote_type, height, *rest):
+        built.append((height, threading.current_thread()
+                      is threading.main_thread()))
+        return real(chain_id, vote_type, height, *rest)
+
+    monkeypatch.setattr(B, "vote_sign_bytes_table", recording)
+
+    async def drive():
+        while reactor.blocks_synced < n:
+            want = min(2 * R.VERIFY_WINDOW + 1, n + 2 - reactor.pool.height)
+            while len(reactor.pool.peek_window(want)) < want:
+                for pid, h in reactor.pool.schedule_requests():
+                    reactor.pool.add_block(pid, src_store.load_block(h))
+            applied = reactor.blocks_synced
+            await reactor._process_window()
+            assert reactor.blocks_synced > applied
+
+    asyncio.run(drive())
+    pipelined = reactor.stage_breakdown()["pipelined_windows"]
+    assert pipelined >= 2
+    on_worker = {h for h, on_loop in built if not on_loop}
+    on_loop = {h for h, on_loop in built if on_loop}
+    # the inline window's commits (heights 1..VERIFY_WINDOW) on the worker,
+    # every later one on the loop thread, each built once
+    assert on_worker and max(on_worker) <= R.VERIFY_WINDOW
+    assert on_loop and min(on_loop) > max(on_worker)
+    assert len(built) == len(on_worker) + len(on_loop)
+    conns.stop()
+    conns2.stop()
+
+
 # -- adversarial: tampered block responses (blocksync.bad_block site) ---------
 
 def test_fast_sync_survives_tampered_block_response(one_val_genesis, monkeypatch):

@@ -337,8 +337,13 @@ def test_commit_columns_memo_and_verify_commit_light(monkeypatch):
     from tendermint_tpu.types.validator import Validator
     from tendermint_tpu.types.validator_set import ValidatorSet
 
+    from tendermint_tpu.types import validator_set as VS
+
     monkeypatch.setenv("TMTPU_BATCH_BACKEND", "host")  # no kernel compiles
-    n = 40  # > 32 engages the batched sign-bytes + columns path
+    # the entry hands a commit over as columns above one stream chunk
+    # (2,048): lowered, so that 40 validators take that way
+    monkeypatch.setattr(VS, "STREAM_CHUNK", 32)
+    n = 40
     keys = [Ed25519PrivKey.generate(bytes([i + 1]) * 32) for i in range(n)]
     vals = [Validator(k.pub_key().address(), k.pub_key(), 10, 0)
             for k in keys]

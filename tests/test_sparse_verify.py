@@ -111,3 +111,47 @@ def test_pk_device_cache_reuses_buffer():
     truth = np.array([host.verify(p, m, s)
                       for p, m, s in zip(pks, msgs, sigs)])
     np.testing.assert_array_equal(v1, truth)
+
+
+def test_columns_alone_verify_as_their_rows_do():
+    """A batch that comes as columns alone (``msgs`` None: the VerifyCommit*
+    entries hand a uniform commit over so, PR 27) packs and verifies as the
+    same batch with its rows, at this file's one shape: 140 equal-length
+    rows that differ in 20 byte positions (the 32-column bucket)."""
+    import hashlib
+
+    from tendermint_tpu.crypto.signcols import sign_columns_from_rows
+
+    rng = np.random.default_rng(27)
+    base = bytes(rng.integers(0, 256, 120, dtype=np.uint8))
+    pks, msgs, sigs = [], [], []
+    for i in range(140):
+        priv = Ed25519PrivateKey.from_private_bytes(
+            bytes(rng.integers(0, 256, 32, dtype=np.uint8)))
+        m = base[:40] + hashlib.sha256(b"%d" % i).digest()[:20] + base[60:]
+        s = priv.sign(m)
+        if i % 11 == 0:
+            s = s[:32] + bytes(32)  # corrupt scalar -> reject
+        pks.append(priv.public_key().public_bytes_raw())
+        msgs.append(m)
+        sigs.append(s)
+    truth = np.array([host.verify(p, m, s)
+                      for p, m, s in zip(pks, msgs, sigs)])
+    assert truth.sum() not in (0, 140)
+    cols = sign_columns_from_rows(msgs)
+    assert cols is not None and cols.rows() == msgs
+    with_rows = V.prepare_sparse_stream(pks, msgs, sigs, 128, columns=cols)
+    alone = V.prepare_sparse_stream(pks, None, sigs, 128, columns=cols)
+    assert with_rows[0][0].shape == (2, 192) and with_rows[0][1].shape == (32,)
+    for a, b in zip(with_rows[0], alone[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(
+        V.batch_verify_stream(pks, None, sigs, chunk=128, columns=cols), truth)
+    np.testing.assert_array_equal(
+        V.batch_verify_stream(pks, msgs, sigs, chunk=128, columns=cols), truth)
+    # below one chunk the one-call program packs rows: built from the columns
+    # (140 rows at chunk 256 would build it; the packing alone is read here)
+    assert V._rows_of(None, cols) == msgs
+    with pytest.raises(ValueError, match="do not align"):
+        V.batch_verify_stream(pks, None, sigs, chunk=128,
+                              columns=cols.slice(0, 100))
