@@ -89,7 +89,9 @@ def _request(data: dict, commit) -> tuple:
 
 def warm(data: dict) -> None:
     """Two calls of the entry on pool data, an accepted and a refused one:
-    the sparse stream pair (K=3, K=2 at 10,240) and both ways out."""
+    the programs this size uses (at 10,000 validators the sparse stream
+    pair, K=3 and K=2; at 150 the one-call 256-lane program) and both ways
+    out."""
     slots = [next(i for i, c in enumerate(data["plain"])
                   if bool(c.tampered_rows) == bad) for bad in (False, True)]
     for slot in slots:

@@ -241,17 +241,44 @@ def memory_peak_bytes() -> int:
     return peak
 
 
+#: the longest requests of a window that the result line keeps
+SLOWEST_KEPT = 8
+
+#: the most bytes a result line may have. What a driver keeps of standard
+#: output is a bounded tail, and a line cut at its head is not JSON: at 150
+#: validators a line that listed every request was 162-173 KB (PERF.md 6).
+RESULT_LINE_LIMIT = 16384
+
+
+def request_rows(requests: List[dict], t0: float) -> List[list]:
+    """Every request as ``[start_s, length_s, units]``: its start from the
+    window's start and its length, in seconds, and its units summed."""
+    return [[round(r["t0"] - t0, 6), round(r["t1"] - r["t0"], 6),
+             sum(r["units"].values())] for r in requests]
+
+
+def slowest(rows: List[list]) -> List[list]:
+    """The ``SLOWEST_KEPT`` longest requests, longest first: a stall shows
+    as one request of seconds among milliseconds (PERF.md, 2)."""
+    return sorted(rows, key=lambda r: -r[1])[:SLOWEST_KEPT]
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              t_process_start: float, overrides: Optional[dict] = None,
-             control: bool = False) -> dict:
+             control: bool = False, requests_out: Optional[str] = None
+             ) -> dict:
     """One run of one cell -> the result object (the caller prints it).
+    Its size does not depend on how many requests the window held.
 
     ``overrides`` replaces keys of the configuration and traffic files
     (the CPU rehearsal's small sizes, a test's planted fault); the
     benchmark's own command never passes any. ``control`` True puts the
     reference's control in the program's place (tests); "also" compares
     the same window a second time with the control answering, under
-    ``compared_control`` (seeds.py)."""
+    ``compared_control`` (seeds.py). ``requests_out`` names a file that
+    gets every request of the window (``request_rows``, as JSON): what a
+    shorter window of the same run would have read (PERF.md, 2); the
+    benchmark's own command never passes it."""
     import counters
 
     bench = load_benchmark()
@@ -330,10 +357,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     out["workload"] = workload
     out["seed"] = seed
     out["window_s"] = t1 - t0
-    # every request's start (from the window's start) and length, seconds:
-    # what a shorter window of the same run would have read (PERF.md, 2)
-    out["requests"] = [[round(r["t0"] - t0, 6), round(r["t1"] - r["t0"], 6),
-                        sum(r["units"].values())] for r in requests]
+    rows = request_rows(requests, t0)
+    if requests_out:
+        with open(requests_out, "w") as f:
+            json.dump(rows, f)
+    out["slowest"] = slowest(rows)
     out["compile"] = {"warm": c_warm, "window": counters.delta(c_window,
                                                                c_warm)}
     if control == "also":
@@ -347,7 +375,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
 def print_result(out: dict) -> None:
     """Each number compared beside its limit as the last lines of stderr,
-    the result object as the last line of stdout."""
+    the result object as the last line of stdout: strict JSON (no NaN, no
+    Infinity) of at most ``RESULT_LINE_LIMIT`` bytes. A line that cannot be
+    read is no result: it is refused here, on the machine that made it,
+    and nothing is printed."""
+    try:
+        line = json.dumps(out, allow_nan=False)
+    except ValueError as e:
+        raise BenchmarkError(f"the result is not strict JSON: {e}") from None
+    if len(line.encode()) > RESULT_LINE_LIMIT:
+        sizes = {k: len(json.dumps(v)) for k, v in out.items()}
+        raise BenchmarkError(
+            f"the result line has {len(line.encode())} bytes, over the "
+            f"{RESULT_LINE_LIMIT} a line may have; its largest key is "
+            f"{max(sizes, key=sizes.get)!r}")
     sys.stdout.flush()
     print(f"correct {out['correct']}: each number compared, beside its "
           "limit", file=sys.stderr)
@@ -355,4 +396,4 @@ def print_result(out: dict) -> None:
         print(f"compared {k}: value {v['value']} limit {v['limit']}",
               file=sys.stderr)
     sys.stderr.flush()
-    print(json.dumps(out), flush=True)
+    print(line, flush=True)

@@ -33,6 +33,18 @@ SMALL = {"validators": 256, "heavy_validators": 48, "blocks": 32,
          "pool_commits": 4, "check_sample": 2}
 
 
+def small_for(workload: str) -> dict:
+    """``SMALL`` as a cap: a size the cell's own files already hold below it
+    stays (``vals150`` rehearses at its own 150 equal-power validators,
+    not as a second copy of ``vals10000`` at 256)."""
+    import harness
+
+    cell = harness.find_cell(harness.load_benchmark(), workload)
+    sizes = {**harness.load_json("configs", cell["config"] + ".json"),
+             **harness.load_json("traffic", cell["traffic"] + ".json")}
+    return {k: min(v, sizes[k]) for k, v in SMALL.items() if k in sizes}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", action="append")
@@ -49,7 +61,8 @@ def main(argv=None) -> int:
     ok = True
     for name in names:
         out = harness.run_cell(name, args.seed, args.seconds, False,
-                               T_PROCESS_START, overrides=SMALL)
+                               T_PROCESS_START,
+                               overrides=small_for(name))
         ok = ok and out["correct"]
         print(json.dumps({
             "rehearsal": True, "workload": name, "correct": out["correct"],

@@ -31,6 +31,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--requests-out", metavar="PATH",
+                    help="by hand: write every request of the window, "
+                    "[[start_s, length_s, units], ...], as JSON to PATH")
     args = ap.parse_args(argv)
 
     import harness
@@ -50,12 +53,13 @@ def main(argv=None) -> int:
                 f"found {device}. The CPU rehearsal is "
                 "benchmarks/rehearse.py")
         out = harness.run_cell(args.workload, args.seed, args.seconds,
-                               bool(args.trace), T_PROCESS_START)
+                               bool(args.trace), T_PROCESS_START,
+                               requests_out=args.requests_out)
+        harness.print_result(out)
     except (harness.BenchmarkError, ImportError, OSError) as e:
         print(f"benchmarks/run.py: no result: {type(e).__name__}: {e}",
               file=sys.stderr)
         return 2
-    harness.print_result(out)
     return 0
 
 
