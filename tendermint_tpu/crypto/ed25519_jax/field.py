@@ -15,6 +15,11 @@ Design notes (why this representation):
 * Carries are TWO data-parallel passes over all limbs (mask/shift/roll/add),
   not a 17-step sequential chain: after column sums < 2^26, pass one leaves
   limbs < 2^16.4, pass two < 2^15+57 — inside the mul input invariant.
+* A multiplication is a few large fused operations, not many small ones: on
+  the v5e the time of the verify programs is the number of passes over
+  (17..34, *batch) arrays in memory, so :func:`mul` sums its columns in
+  registers from statically padded operands (see there for what the earlier
+  in-place build cost).
 
 Invariant: limbs entering :func:`mul` are ``<= 2^15 + 57`` (guaranteed by
 :func:`carry`); products then stay < 2^31 and split column sums < 2^22.
@@ -138,51 +143,64 @@ def neg(a: jnp.ndarray) -> jnp.ndarray:
     return carry(two_p - a)
 
 
+def _shifted(x: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """(17, *batch) -> (34, *batch): ``x`` behind ``lead`` zero limbs."""
+    pad = [(lead, NLIMBS - lead, 0)] + [(0, 0, 0)] * (x.ndim - 1)
+    return jax.lax.pad(x, jnp.uint32(0), pad)
+
+
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Field multiply. Inputs carry-normalized (limbs <= 2^15+57).
 
-    Columns are accumulated with static-slice scatter-adds
-    (``cols.at[i:i+NLIMBS].add``). A jnp.roll-based column build miscompiles
-    inside ``lax.fori_loop`` on the TPU backend (verified empirically: valid
-    signatures rejected on-device while CPU agrees with the host spec), so
-    this MUST stay scatter-based; the differential on-device suite in
-    tests/test_tpu_device.py guards it.
+    The 34 columns of the schoolbook product are ONE fused sum: row i is
+    ``a_i`` times ``b`` shifted i limbs up (a static zero-pad of the operand,
+    never of a product), its low 15 bits taken in place and the rest from the
+    same product one limb further; the 17 rows are added with plain ``+``.
+    The compiler keeps all of it in registers, drops the padded zeros and
+    spends the second multiplication more cheaply than a shifted copy of an
+    intermediate: on the v5e a multiplication reads 0.55 us at 256 lanes and
+    0.78 us at 2,048 (PERF.md §6, PR 33).
+
+    It replaced an in-place build (``cols.at[i:i+17].add(lo[i])``, 34
+    updates a multiplication), which compiled to a device operation per
+    update, each a pass over a (34, *batch) array in memory: 33 of the 43
+    operations of a multiplication, 1.44 / 3.29 us. The same lo/hi split,
+    the same sums in another order (uint32 addition is associative and the
+    column sums stay under 2^22), the same fold and carry: every limb of
+    every product is bit-identical to that build's
+    (tests/test_field_jax.py keeps it as the reference).
+
+    That build was itself a work-around: round 1's ``jnp.roll`` column build
+    miscompiled inside ``lax.fori_loop`` on the TPU backend of the time
+    (valid signatures rejected on-device while CPU agreed with the host
+    spec). ``roll`` is still not used, here or in :func:`carry`; what guards
+    the build on the chip is tests/test_tpu_device.py (the field chain under
+    ``fori_loop`` and the differential corpora) and chip_smoke.py, and
+    tests/test_chip_compile.py holds the operations a multiplication and a
+    whole verify program execute on the described v5e.
     """
-    prod = a[:, None] * b[None]                   # (17, 17, *batch), < 2^31
-    lo = prod & MASK                              # <= 2^15-1
-    hi = prod >> RADIX                            # < 2^16
-    batch_shape = prod.shape[2:]
-    cols = jnp.zeros((2 * NLIMBS,) + batch_shape, dtype=jnp.uint32)
+    cols = None
     for i in range(NLIMBS):
-        cols = cols.at[i:i + NLIMBS].add(lo[i])
-        cols = cols.at[i + 1:i + 1 + NLIMBS].add(hi[i])
+        ai = a[i][None]
+        row = ((ai * _shifted(b, i)) & MASK) + ((ai * _shifted(b, i + 1)) >> RADIX)
+        cols = row if cols is None else cols + row
     # fold columns 17.. back with x19 (2^255 ≡ 19): c_j += 19*c_{j+17}
-    folded = cols[:NLIMBS] + 19 * cols[NLIMBS:]
-    return carry(folded)
+    return carry(cols[:NLIMBS] + 19 * cols[NLIMBS:])
 
 
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
-    """Field square: exploits product symmetry (p_ij + p_ji = 2·p_ij) to do
-    153 limb products instead of mul's 289 (~35% cheaper on the VPU).
+    """Field square: ``mul(a, a)``.
 
-    Cross terms use a pre-doubled operand: a2 = 2a has limbs < 2^16+114, so
-    a2_i * a_j < 2^31.1 < 2^32 (uint32-safe); split columns then bound the
-    same as :func:`mul`.
+    The symmetric form (153 products: a_i^2 and 2 a_i a_j for i < j) saves
+    multiplications the v5e does not miss and costs rows of unequal length
+    it does: fused, its variants read 0.91-7.9 us at 256 lanes where
+    :func:`mul` reads 0.55, and the in-place symmetric build read 1.21
+    (PERF.md §6, PR 33). The value is the same; the redundant limbs differ from the
+    symmetric build's in about one squaring in a million (lo(2p) + hi(2p)
+    against 2 lo(p) + 2 hi(p) before the carry), inside the same invariant,
+    and every verdict reads a field element through :func:`freeze`.
     """
-    a2 = a + a
-    batch_shape = a.shape[1:]
-    cols = jnp.zeros((2 * NLIMBS,) + batch_shape, dtype=jnp.uint32)
-    for i in range(NLIMBS):
-        # row i: diagonal a_i^2 at column 2i, then doubled cross terms
-        # a2_i * a_j for j in (i, 17) at columns i+j — one contiguous slice
-        row = jnp.concatenate([a[i:i + 1] * a[i:i + 1], a2[i:i + 1] * a[i + 1:]], axis=0)
-        lo = row & MASK
-        hi = row >> RADIX
-        width = NLIMBS - i
-        cols = cols.at[2 * i:2 * i + width].add(lo)
-        cols = cols.at[2 * i + 1:2 * i + 1 + width].add(hi)
-    folded = cols[:NLIMBS] + 19 * cols[NLIMBS:]
-    return carry(folded)
+    return mul(a, a)
 
 
 def mul_small(a: jnp.ndarray, k: int) -> jnp.ndarray:

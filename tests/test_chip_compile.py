@@ -17,7 +17,9 @@ a module is imported — every compile happens in the test's own process,
 and all of these tests live in this one file.
 """
 
+import re
 import time
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -103,6 +105,63 @@ def _i32(sh, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
 
+# --- executed operations of a compiled program --------------------------------
+
+_NOT_EXECUTED = {"parameter", "constant", "tuple", "get-tuple-element",
+                 "bitcast"}
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][\w\-]*)\(")
+
+
+def executed_ops(hlo_text):
+    """Operations one call of a compiled program executes on the chip,
+    loops weighted: the instructions of the entry computation, plus those
+    of every ``while`` body times its trip count (every loop of the verify
+    programs counts up from 0 to a constant: ``fori_loop(0, n)``, ``scan``;
+    the bound is read off the loop's condition). Parameters, constants,
+    tuples and bitcasts are not executed and not counted; a fusion counts
+    once, whatever it fuses. Returns ``(total, Counter by opcode)``.
+
+    A property of the build, not a time: it tells an in-place column build
+    (an operation an update) from a fused one at no chip time, and PERF.md
+    §5 keeps the reading beside each kernel time. What an operation costs
+    on the chip is what it moves through memory (PERF.md §6, PR 33: a
+    quarter of the operations read slower)."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        head = None if line.startswith(" ") else _COMPUTATION.match(line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = _INSTRUCTION.match(line)
+            if m:
+                cur.append((m.group(1), line))
+
+    def trips(while_line):
+        cond = re.search(r"condition=%?([\w.\-]+)", while_line).group(1)
+        text = "\n".join(line for _, line in comps[cond])
+        bounds = re.findall(r"s32\[\][^ ]* constant\((\d+)\)", text)
+        assert len(bounds) == 1 and "direction=LT" in text, text
+        return int(bounds[0])
+
+    by = Counter()
+
+    def walk(name, weight):
+        for op, line in comps[name]:
+            if op in _NOT_EXECUTED:
+                continue
+            by[op] += weight
+            if op == "while":
+                body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+                walk(body, weight * trips(line))
+
+    walk(entry, 1)
+    return sum(by.values()), by
+
+
 # --- tier-1: building blocks at the deployed lane width ---------------------
 
 @pytest.mark.parametrize("op", ["mul", "sqr", "inverse", "pow_p58"])
@@ -113,6 +172,27 @@ def test_field_op_compiles(one_chip, op):
         _compile(lambda a, b: F.carry(F.mul(a, b)), fe, fe)
     else:
         _compile(getattr(F, op), fe)
+
+
+# What F.mul (and F.sqr, which is mul(a, a)) reads on the described v5e with
+# the fused column build (PR 33), at 2 sublanes and at 16 alike: the
+# operand's copy into fast memory, one fusion for its 17 limbs, one for all
+# 34 columns, and carry's seven. The in-place build read 43 (sqr 63), 33
+# (52) of them column updates.
+FIELD_MUL_OPS = 11
+
+
+@pytest.mark.parametrize("sublanes", [2, 16])
+@pytest.mark.parametrize("op", ["mul", "sqr"])
+def test_field_columns_are_one_fused_sum(one_chip, op, sublanes):
+    """No column update comes back into mul / sqr: each in-place update is a
+    device operation of its own, 34 a multiplication."""
+    fe = _fe(one_chip, (sublanes, V.LANE))
+    compiled = _compile(getattr(F, op), *([fe] * (2 if op == "mul" else 1)))
+    text = compiled.as_text()
+    total, by = executed_ops(text)
+    assert "dynamic-update-slice" not in text
+    assert total <= FIELD_MUL_OPS + 2, (total, dict(by))
 
 
 def test_curve_add_compiles(one_chip):
@@ -174,13 +254,30 @@ def test_psum_tally_compiles_on_four_chip_mesh(mesh4):
 
 # --- slow: the whole kernels at the shapes chip_smoke.py runs ---------------
 
-def _report(name, compiled, t0):
+# Executed operations of one verification pass over a batch (one call of
+# ``_verify_kernel``, one chunk of a stream), loops weighted: 54,315-54,858
+# with the fused column build (PR 33, lane buckets 128 to 2,048; 55,600 a
+# chunk of the sparse stream), 198,130-201,203 with the in-place build,
+# 175,000 of them column updates. The ceiling keeps that build from coming
+# back unnoticed; a program of K chunks may execute K times as many.
+VERIFY_OPS_CEILING = 60_000
+
+
+def _report(name, compiled, t0, chunks=1):
+    """Prints what the compile cost and the program's executed operations
+    (PERF.md §5 keeps them beside each kernel time), and holds them under
+    ``chunks`` times the ceiling."""
     m = compiled.memory_analysis()
+    text = compiled.as_text()
+    total, by = executed_ops(text)
     print(f"\nAOT {name}: compile {time.perf_counter() - t0:.1f}s "
           f"code {m.generated_code_size_in_bytes / 1e6:.1f}MB "
           f"args {m.argument_size_in_bytes / 1e6:.2f}MB "
           f"out {m.output_size_in_bytes / 1e6:.3f}MB "
-          f"temp {m.temp_size_in_bytes / 1e6:.2f}MB")
+          f"temp {m.temp_size_in_bytes / 1e6:.2f}MB "
+          f"hlo {len(text) / 1e6:.1f}MB executed ops {total:,} "
+          f"({by['fusion']:,} fusions)")
+    assert total <= chunks * VERIFY_OPS_CEILING, (name, total, dict(by))
 
 
 @pytest.mark.slow
@@ -213,7 +310,8 @@ def test_sparse_stream_kernel_compiles(one_chip, k, n_cols):
     t0 = time.perf_counter()
     compiled = _compile(V._verify_sparse_stream_kernel.__wrapped__,
                         *_sparse_specs(one_chip, k, n_cols))
-    _report(f"_verify_sparse_stream_kernel[K={k},C={n_cols}]", compiled, t0)
+    _report(f"_verify_sparse_stream_kernel[K={k},C={n_cols}]", compiled, t0,
+            chunks=k)
 
 
 @pytest.mark.slow
@@ -224,7 +322,7 @@ def test_dense_stream_kernel_compiles(one_chip, k):
     compiled = _compile(V._verify_stream_kernel.__wrapped__,
                         _u32(sh, k, NBLK, 32, *BATCH), _i32(sh, k, *BATCH),
                         _u32(sh, k, 8, *BATCH))
-    _report(f"_verify_stream_kernel[K={k}]", compiled, t0)
+    _report(f"_verify_stream_kernel[K={k}]", compiled, t0, chunks=k)
 
 
 @pytest.mark.slow

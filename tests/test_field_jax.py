@@ -114,3 +114,136 @@ def test_eq_is_zero_parity():
     z = np.asarray(F.is_zero(a))
     assert list(z) == [True, False, False]
     assert list(np.asarray(F.parity(a))) == [0, 1, 0]
+
+
+# --- the fused column build against the in-place build it replaced ----------
+#
+# field.mul used to accumulate its 34 columns with 34 in-place updates
+# (``cols.at[i:i+17].add``), and field.sqr had a symmetric build of its own.
+# The references below keep both, in numpy. The fused mul must give every
+# limb of every product bit for bit: at the edges of the input invariant, at
+# the batch shapes the kernels use, and inside ``lax.fori_loop`` (where round
+# 1's roll-based build went wrong on the TPU; tests/test_tpu_device.py
+# repeats the check on the chip). sqr is now mul(a, a): limb for limb the
+# in-place PRODUCT of a with itself, and the same field element as the
+# symmetric build (whose redundant limbs differ in about one squaring in a
+# million: field.sqr says why).
+
+LIMB_MAX = 2**15 + 57            # what carry() guarantees, and mul() accepts
+
+
+def _carry_np(c):
+    c = c.astype(np.uint32)
+    for _ in range(2):
+        lo = c & np.uint32(F.MASK)
+        hi = c >> np.uint32(F.RADIX)
+        c = lo + np.concatenate([hi[F.NLIMBS - 1:] * np.uint32(19),
+                                 hi[:F.NLIMBS - 1]], axis=0)
+    return c
+
+
+def _fold_np(cols):
+    return _carry_np(cols[:F.NLIMBS] + np.uint32(19) * cols[F.NLIMBS:])
+
+
+def _mul_inplace(a, b):
+    n = F.NLIMBS
+    prod = a[:, None] * b[None]
+    lo, hi = prod & np.uint32(F.MASK), prod >> np.uint32(F.RADIX)
+    cols = np.zeros((2 * n,) + a.shape[1:], dtype=np.uint32)
+    for i in range(n):
+        cols[i:i + n] += lo[i]
+        cols[i + 1:i + 1 + n] += hi[i]
+    return _fold_np(cols)
+
+
+def _sqr_inplace(a):
+    n = F.NLIMBS
+    a2 = a + a
+    cols = np.zeros((2 * n,) + a.shape[1:], dtype=np.uint32)
+    for i in range(n):
+        row = np.concatenate([a[i:i + 1] * a[i:i + 1], a2[i:i + 1] * a[i + 1:]])
+        width = n - i
+        cols[2 * i:2 * i + width] += row & np.uint32(F.MASK)
+        cols[2 * i + 1:2 * i + 1 + width] += row >> np.uint32(F.RADIX)
+    return _fold_np(cols)
+
+
+def _operands(kind, batch, seed):
+    """Two (17, *batch) uint32 operands inside mul's input invariant."""
+    rng = np.random.default_rng(seed)
+    shape = (F.NLIMBS,) + batch
+    loose = lambda: rng.integers(0, LIMB_MAX + 1, size=shape, dtype=np.uint32)
+    if kind == "all_limbs_max":
+        a = np.full(shape, LIMB_MAX, dtype=np.uint32)
+        b = a.copy()
+    elif kind == "zeros_and_ones":
+        a = rng.integers(0, 2, size=shape, dtype=np.uint32)
+        b = rng.integers(0, 2, size=shape, dtype=np.uint32)
+        a[:, 0, 0] = 0                      # 0 · x
+        b[:, 0, 1] = 0
+        a[:, 0, 2] = [1] + [0] * 16         # 1 · x
+        b[:, 0, 3] = 1                      # every limb 1
+    elif kind == "frozen_times_loose":
+        a = rng.integers(0, 2**15, size=shape, dtype=np.uint32)
+        a = np.asarray(F.freeze(a))         # canonical: limbs strictly 15-bit
+        b = loose()
+        b[:, -1, -1] = LIMB_MAX
+    else:
+        assert kind == "loose"
+        a, b = loose(), loose()
+        a[:, 0, 0] = LIMB_MAX
+        b[:, 0, 0] = LIMB_MAX
+    return a, b
+
+
+def _lanes_as_ints(x, k=48):
+    flat = np.asarray(x).reshape(F.NLIMBS, -1)
+    return [F.limbs_to_int(flat[:, i]) for i in range(min(k, flat.shape[1]))]
+
+
+@pytest.mark.parametrize("batch", [(2, 128), (16, 128)],
+                         ids=lambda b: "x".join(map(str, b)))
+@pytest.mark.parametrize("kind", ["all_limbs_max", "zeros_and_ones",
+                                  "frozen_times_loose", "loose"])
+@pytest.mark.parametrize("op", ["mul", "sqr"])
+def test_fused_columns_match_inplace_build(op, kind, batch):
+    import jax
+
+    a, b = _operands(kind, batch, seed=len(kind) + batch[0])
+    if op == "mul":
+        got = np.asarray(jax.jit(F.mul)(a, b))
+        want = _mul_inplace(a, b)
+        ints = [x * y % P for x, y in zip(_lanes_as_ints(a), _lanes_as_ints(b))]
+    else:
+        got = np.asarray(jax.jit(F.sqr)(b))
+        want = _mul_inplace(b, b)
+        ints = [x * x % P for x in _lanes_as_ints(b)]
+        assert np.array_equal(np.asarray(F.freeze(got)),
+                              np.asarray(F.freeze(_sqr_inplace(b))))
+    assert got.dtype == np.uint32 and got.shape == want.shape
+    assert np.array_equal(got, want), np.argwhere(got != want)[:4]
+    assert int(got.max()) <= LIMB_MAX
+    assert [v % P for v in _lanes_as_ints(got)] == ints
+
+
+@pytest.mark.parametrize("batch", [(2, 128), (16, 128)],
+                         ids=lambda b: "x".join(map(str, b)))
+def test_fused_columns_inside_fori_loop(batch):
+    """Squarings (and one multiply a trip) under lax.fori_loop: the shape in
+    which round 1's roll-based build miscompiled on the TPU."""
+    import jax
+
+    trips = 6
+    a, b = _operands("loose", batch, seed=33)
+
+    @jax.jit
+    def chain(x, y):
+        return jax.lax.fori_loop(0, trips, lambda _, v: F.mul(F.sqr(v), y), x)
+
+    want, squares = a, a
+    for _ in range(trips):
+        want = _mul_inplace(_mul_inplace(want, want), b)
+        squares = _mul_inplace(squares, squares)
+    assert np.array_equal(np.asarray(chain(a, b)), want)
+    assert np.array_equal(np.asarray(F._sqr_n(a, trips)), squares)

@@ -81,6 +81,36 @@ def test_device_field_mul_matches_bigint():
         assert F.limbs_to_int(out[:, i]) == a_int[i] * b_int[i] % F.P_INT
 
 
+@pytest.mark.parametrize("sublanes", [2, 16])
+def test_device_field_chain_in_fori_loop_matches_bigint(sublanes):
+    """Squarings and multiplies under lax.fori_loop on the chip, at the
+    kernels' batch shapes: where round 1's roll-based column build went
+    wrong, and what the fused build (field.mul) has to get right."""
+    assert _device_is_accelerator()
+    import jax
+    from tendermint_tpu.crypto.ed25519_jax import field as F
+
+    rng = np.random.default_rng(sublanes)
+    shape = (F.NLIMBS, sublanes, 128)
+    a = rng.integers(0, 2**15 + 58, size=shape, dtype=np.uint32)
+    b = rng.integers(0, 2**15 + 58, size=shape, dtype=np.uint32)
+    a[:, 0, 0] = b[:, 0, 0] = 2**15 + 57        # the invariant's edge
+    trips = 40
+
+    @jax.jit
+    def chain(x, y):
+        x = jax.lax.fori_loop(0, trips, lambda _, v: F.mul(F.sqr(v), y), x)
+        return F.freeze(x)
+
+    out = np.asarray(chain(a, b)).reshape(F.NLIMBS, -1)
+    af, bf = a.reshape(F.NLIMBS, -1), b.reshape(F.NLIMBS, -1)
+    for i in range(0, af.shape[1], 37):
+        want, y = F.limbs_to_int(af[:, i]), F.limbs_to_int(bf[:, i])
+        for _ in range(trips):
+            want = want * want * y % F.P_INT
+        assert F.limbs_to_int(out[:, i]) == want, i
+
+
 def test_device_segmented_pipeline_matches_host():
     """The segmented double-buffered stream path (the flagship 10k
     optimization) on the real chip: verdicts must be byte-identical to the
