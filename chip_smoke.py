@@ -77,6 +77,19 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
+
+def _benchmark_module(name: str):
+    """A module of benchmarks/ (scripts that import each other by bare
+    name, so the directory goes on sys.path): the smoke shares the
+    benchmark's compile counter and its fast-sync chain and replay."""
+    import importlib
+
+    bench_dir = os.path.join(HERE, "benchmarks")
+    if bench_dir not in sys.path:
+        sys.path.insert(0, bench_dir)
+    return importlib.import_module(name)
+
+
 class SmokeFailure(AssertionError):
     pass
 
@@ -178,49 +191,6 @@ def host_verdicts(pks, msgs, sigs) -> np.ndarray:
 
 # --- counters the program already keeps -------------------------------------
 
-class CompileCounter:
-    """Programs this process asked XLA for, from jax's monitoring events:
-    every request ends in one backend_compile_duration event, and one the
-    persistent cache served fires cache_hits first, on the same thread."""
-
-    REQUEST = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        import jax
-
-        self._tl = threading.local()
-        self._lock = threading.Lock()
-        self.c = {"programs": 0, "cache_served": 0, "compiled": 0,
-                  "compiled_over_2s": 0, "compile_s": 0.0, "load_s": 0.0}
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-
-    def _on_event(self, event, **_kw):
-        if event == self.HIT:
-            self._tl.hit = True
-
-    def _on_secs(self, event, secs, **_kw):
-        if event != self.REQUEST:
-            return
-        hit = getattr(self._tl, "hit", False)
-        self._tl.hit = False
-        with self._lock:
-            self.c["programs"] += 1
-            if hit:
-                self.c["cache_served"] += 1
-                self.c["load_s"] += secs
-            else:
-                self.c["compiled"] += 1
-                self.c["compile_s"] += secs
-                if secs >= 2.0:
-                    self.c["compiled_over_2s"] += 1
-
-    def snap(self) -> dict:
-        with self._lock:
-            return dict(self.c)
-
-
 def _delta(after: dict, before: dict) -> dict:
     out = {}
     for k, v in after.items():
@@ -243,7 +213,7 @@ class Smoke:
     def __init__(self, args, platform: str):
         self.args = args
         self.platform = platform          # what segments must be labelled
-        self.compiles = CompileCounter()
+        self.compiles = _benchmark_module("counters").CompileCounter()
         self.failed = []
         self.warnings = _WarningTap()
         logging.getLogger("tmtpu").addHandler(self.warnings)
@@ -511,25 +481,31 @@ class Smoke:
     # -- phase 4 -------------------------------------------------------------
 
     def fast_sync(self, before):
-        import bench
+        replay = _benchmark_module("drivers.fast_sync_replay")
 
         n_vals = 256 if self.args.rehearse else 1000
         windows = 2 if self.args.rehearse else 3
         n = 16 * windows
         t0 = time.perf_counter()
-        genesis, blocks, marks = bench.build_sync_chain(
-            n_vals, n, "smoke-sync", seed=self.args.seed)
+        data = replay.build(
+            {"validators": n_vals, "power": 10, "blocks": n,
+             "verify_window_pairs": 16},
+            {"chain_id": "smoke-sync", "tampered_chains": []},
+            self.args.seed)
         build_s = time.perf_counter() - t0
+        chain = data["sound"]
+        # block n + 1 carries the source state after height n
+        source = chain["blocks"][n].header
 
         def sync():
-            reactor = bench.replay_sync_chain(genesis, blocks, n)
+            reactor = replay._sync(data, chain)
             st = reactor.state
             check(st.last_block_height == n,
                   f"synced to {st.last_block_height}, source is at {n}")
-            check(st.app_hash == marks[n][0],
+            check(st.app_hash == source.app_hash,
                   f"app hash {st.app_hash.hex()} != source "
-                  f"{marks[n][0].hex()}")
-            check(st.last_block_id == marks[n][1],
+                  f"{source.app_hash.hex()}")
+            check(st.last_block_id == source.last_block_id,
                   "last block ID differs from the source chain's")
             return reactor.stage_breakdown()
 
@@ -539,7 +515,7 @@ class Smoke:
         per_sync = n * 2 * n_vals - n_vals
         out = {"validators": n_vals, "blocks": n, "windows": windows,
                "build_chain_s": round(build_s, 2), "sync": t,
-               "app_hash": marks[n][0].hex(),
+               "app_hash": source.app_hash.hex(),
                "pipelined_windows": steady["pipelined_windows"],
                "inline_windows": steady["inline_windows"]}
         out["route"] = self.check_routes(before, 2 * per_sync, 2 * per_sync)

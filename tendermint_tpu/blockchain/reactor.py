@@ -118,9 +118,8 @@ class BlockchainReactor(Reactor):
         self._prepared: Optional[_PreparedWindow] = None
         # per-stage histograms + pipeline counters (libs/metrics.py
         # BlocksyncMetrics). The node rebinds this to its shared registry so
-        # the series land on /metrics; standalone reactors (bench, tests)
-        # keep this private set. bench.py derives the old stage_times
-        # breakdown from the histogram sums via stage_breakdown().
+        # the series land on /metrics; standalone reactors (benchmark, tests)
+        # keep this private set and read it through stage_breakdown().
         self.metrics = BlocksyncMetrics(Registry())
         # untrusted-provider scoring (libs/peerscore.py): a bad block is a
         # strike — exponential backoff keeps the offender out of the pool,
@@ -136,9 +135,8 @@ class BlockchainReactor(Reactor):
             bans_counter=self.metrics.peer_bans_total)
 
     def stage_breakdown(self) -> dict:
-        """The bench-facing view of the stage metrics: cumulative seconds
-        per stage + window counters — the same numbers the old stage_times
-        dict accumulated, now derived from the metric set."""
+        """The benchmark's and the chip smoke's view of the stage metrics:
+        cumulative seconds per stage + window counters."""
         m = self.metrics
         return {
             "hash_s": m.stage_seconds.sum_value("hash"),
@@ -155,8 +153,8 @@ class BlockchainReactor(Reactor):
         state/execution.py records one ``plane="exec"`` segment per applied
         block (validate=pack, tx execution=in-flight, commit+persist=fetch),
         so the same interval-union accounting that profiles the device
-        verify plane decomposes block execution — bench's ``exec`` config
-        reports the in-flight (execute) share vs validate/commit overhead.
+        verify plane decomposes block execution — tools/execbench.py reports
+        the in-flight (execute) share vs validate/commit overhead.
         Stage A's verify-commit(H+1) runs concurrently with these segments;
         its time lives in ``stage_breakdown()`` verify_s, not here."""
         recs = [r for r in phases.recent_segments()

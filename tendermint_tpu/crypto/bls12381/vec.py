@@ -24,7 +24,7 @@ dispatch makes it orders of magnitude slower than the jitted rounds).
 Honesty note (measured on this host, CPU XLA): per-op dispatch overhead
 makes both vector backends *slower* than the scalar Python path at every
 realistic validator count — they exist as the device on-ramp and are gated
-off by default; `bench.py --config aggsig` reports the scalar numbers.
+off by default.
 """
 
 from __future__ import annotations

@@ -17,11 +17,6 @@ segments **round-robin across a device pool**, with:
   collapsing the whole verification plane to host fallback. Only when
   every lane is sick does the call raise, and then the caller's shared
   ``device_breaker`` fallback takes over exactly as before;
-* **device-aware segment sizing** fed by the PR 8 cost model
-  (``tools/device_profile.py cost-model`` output via
-  ``TMTPU_DEVICE_PROFILE``): segments are sized so per-dispatch fixed cost
-  stays a small fraction of per-segment transfer time. ``TMTPU_SEG_CHUNKS``
-  still overrides everything;
 * **per-lane chaos sites** ``device.lane.<platform>:<id>`` (libs/faults):
   arm exactly one device label and watch the pool degrade.
 
@@ -33,9 +28,10 @@ PR 8 ``crypto_device_dispatch_total{device}`` / ``crypto_device_inflight``
 series and the Perfetto segment tracks show per-chip occupancy for free.
 
 Knobs: ``TMTPU_VERIFY_DEVICES`` (device count; 0/1 disables the pool,
-unset = all visible devices), ``TMTPU_MULTIDEV_MIN_SIGS`` (engage
-threshold, default 2x SEG_MIN_SIGS), ``TMTPU_DEVICE_BREAKER_THRESHOLD`` /
-``TMTPU_DEVICE_BREAKER_COOLDOWN_S`` (per-lane breakers). On machines with
+unset = all visible devices), ``TMTPU_DEVICE_BREAKER_THRESHOLD`` /
+``TMTPU_DEVICE_BREAKER_COOLDOWN_S`` (per-lane breakers). The pool engages
+from 2 x ``verify.SEG_MIN_SIGS`` signatures, in segments of at most
+``verify.SEG_CHUNKS`` chunks. On machines with
 one physical chip, ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
 exercises the full dispatch topology against a forced host mesh.
 """
@@ -44,7 +40,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import json
 import logging
 import os
 import threading
@@ -64,62 +59,14 @@ from . import verify as V
 logger = logging.getLogger("tmtpu.multidevice")
 
 ENV_DEVICES = "TMTPU_VERIFY_DEVICES"
-ENV_MIN_SIGS = "TMTPU_MULTIDEV_MIN_SIGS"
-ENV_PROFILE = "TMTPU_DEVICE_PROFILE"
 
 #: fault-site family: one site per lane, e.g. ``device.lane.tpu:3``
 LANE_SITE_PREFIX = "device.lane."
-
-#: keep per-dispatch fixed cost under ~1/OVERHEAD_TARGET of a segment's
-#: transfer time when sizing segments from a cost model
-OVERHEAD_TARGET = 9.0
-#: ~wire bytes per signature on the dense path (R+A+s + padded preimage)
-APPROX_BYTES_PER_SIG = 300.0
 
 
 class AllLanesFailed(RuntimeError):
     """Every pool lane is sick or failed this batch; the caller's shared
     device_breaker / host-fallback path takes over."""
-
-
-def _seg_chunks_from_cost_model(doc: dict, chunk: int = 2048) -> Optional[int]:
-    """Segment size (in scan chunks) from a device_profile cost-model doc:
-    big enough that the fixed dispatch cost is <= ~1/OVERHEAD_TARGET of the
-    segment's per-thread transfer time. None when the doc lacks the
-    numbers (e.g. bandwidth below the ladder's noise floor)."""
-    try:
-        res = doc["results"]
-        fixed_s = float(res["fixed_dispatch_ms"]["min"]) / 1e3
-        bw = res["transfer"]["bandwidth_mbps"]
-        if bw is None or bw <= 0 or fixed_s <= 0:
-            return None
-        chunk_transfer_s = chunk * APPROX_BYTES_PER_SIG / (bw * (1 << 20))
-        if chunk_transfer_s <= 0:
-            return None
-        need = OVERHEAD_TARGET * fixed_s / chunk_transfer_s
-        return max(2, min(64, -(-int(need * 1000) // 1000)))
-    except (KeyError, TypeError, ValueError):
-        return None
-
-
-def default_seg_chunks() -> int:
-    """Per-lane segment size: TMTPU_SEG_CHUNKS wins; else a cost model
-    named by TMTPU_DEVICE_PROFILE; else verify.SEG_CHUNKS."""
-    if os.environ.get("TMTPU_SEG_CHUNKS"):
-        return V.SEG_CHUNKS  # verify.py already parsed the env knob
-    path = os.environ.get(ENV_PROFILE)
-    if path:
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-            if doc.get("kind") == "cost-model":
-                derived = _seg_chunks_from_cost_model(doc)
-                if derived is not None:
-                    return derived
-        except (OSError, ValueError) as e:
-            logger.warning("%s=%r unusable (%s); using SEG_CHUNKS=%d",
-                           ENV_PROFILE, path, e, V.SEG_CHUNKS)
-    return V.SEG_CHUNKS
 
 
 def plan_segments(k_total: int, n_lanes: int,
@@ -167,12 +114,10 @@ class MultiDeviceStream:
         if devices is None:
             devices = jax.devices()
         self.lanes = [DeviceLane(i, d) for i, d in enumerate(devices)]
-        env_min = os.environ.get(ENV_MIN_SIGS)
         self.min_sigs = (min_sigs if min_sigs is not None
-                         else int(env_min) if env_min
                          else 2 * V.SEG_MIN_SIGS)
         self.seg_chunks = (seg_chunks if seg_chunks is not None
-                           else default_seg_chunks())
+                           else V.SEG_CHUNKS)
         self.stats = collections.Counter()
 
     # -- health -------------------------------------------------------------
@@ -373,8 +318,8 @@ def reset_pool() -> None:
 
 @contextlib.contextmanager
 def disabled():
-    """Force the single-device path inside the block (bench A/B runs and
-    parity tests measure 'what would this cost without the pool')."""
+    """Force the single-device path inside the block (the chip smoke's A/B
+    and parity tests measure 'what would this cost without the pool')."""
     global _POOL, _POOL_RESOLVED
     with _POOL_LOCK:
         prev = (_POOL, _POOL_RESOLVED)

@@ -31,7 +31,6 @@ enforce this on valid, corrupted, and adversarial inputs.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from functools import partial
@@ -250,9 +249,8 @@ class PackScratch:
     """Per-worker reusable host packing buffers.
 
     The stream packer used to allocate (and page-fault) a fresh multi-MB
-    preimage matrix per segment — a measurable slice of the pack share the
-    bench gates. Intermediates now reuse one
-    per-thread buffer per dtype, re-zeroed in place (memset, no fault
+    preimage matrix per segment — a measurable slice of the pack share.
+    Intermediates now reuse one per-thread buffer per dtype, re-zeroed in place (memset, no fault
     storm). ONLY intermediates: arrays handed across the device boundary
     are freshly allocated every call, because jax may alias aligned host
     buffers on the CPU backend and a reused buffer could be overwritten
@@ -644,9 +642,8 @@ def batch_verify(
 
 def _pack_stream_dense(pks, msgs, sigs, chunk: int):
     """Dense stream packing: (kernel args (K, ..) tuple, ok mask). Shared
-    by _dispatch_stream's dense branch, the multi-device lanes, and
-    tools/device_profile.py's per-device scale cells (which device_put the
-    same arrays onto an explicit device).
+    by _dispatch_stream's dense branch and the multi-device lanes (which
+    device_put the same arrays onto an explicit device).
 
     Intermediates ride the per-worker PackScratch (no fresh multi-MB
     allocation per segment); the three returned arrays are freshly
@@ -700,10 +697,10 @@ def _dispatch_stream(pks, msgs, sigs, chunk: int, device=None, columns=None):
 # in-flight execution (the gain on a locally attached chip: not measured).
 # Segments of SEG_CHUNKS scan-chunks bound both
 # the per-dispatch payload and the number of distinct compiled K shapes.
-SEG_CHUNKS = max(1, int(os.environ.get("TMTPU_SEG_CHUNKS", "10")))
+SEG_CHUNKS = 10
 # below this many signatures a single dispatch wins (and small CPU test
 # batches never trigger fresh XLA compiles of segment-shaped kernels)
-SEG_MIN_SIGS = int(os.environ.get("TMTPU_SEG_MIN_SIGS", "8192"))
+SEG_MIN_SIGS = 8192
 _SEG_POOL = None
 _SEG_POOL_LOCK = threading.Lock()
 
@@ -780,8 +777,8 @@ def _verify_segmented(pks, msgs, sigs, chunk: int,
     if t_entry is not None:
         # charge the stream entry's host work (bucket grouping over every
         # message) to segment 0's pack phase: it is critical-path packing
-        # cost, and leaving it unattributed would leave a hole in the
-        # wall-clock accounting bench.py asserts over
+        # cost, and leaving it unattributed would leave a hole in
+        # phase_breakdown's wall-clock accounting
         recs[0].t0 = t_entry
     pool = _seg_pool()
     # segment 0 packs+dispatches on the calling thread: on a cold jit cache

@@ -13,8 +13,8 @@ so the cost model is *measured by the system itself*:
   closes the record when the verdict array is on the host. By construction
   ``pack_s + dispatch_s + fetch_s == t_end - t0`` for every record.
 * a bounded ring of the last :data:`RING_CAPACITY` records plus cumulative
-  :func:`phase_totals` — the inputs ``tools/device_profile.py`` and the
-  debugdump ``device.json`` snapshot read;
+  :func:`phase_totals` — what the benchmark's per-layer metrics, the
+  chip smoke and the debugdump ``device.json`` snapshot read;
 * a ``DeviceMetrics`` hook (:func:`set_device_metrics`, wired by the node
   like ``crypto.batch.set_crypto_metrics``): phase histograms
   ``crypto_segment_phase_seconds{phase,plane}``, the per-segment size
@@ -26,8 +26,7 @@ so the cost model is *measured by the system itself*:
   device-pipeline occupancy next to the consensus stage timeline;
 * :func:`phase_breakdown` — interval-union decomposition of a wall-clock
   window into exposed pack / exposed dispatch / device-in-flight shares
-  (the shares sum to the accounted fraction of wall time — bench.py's
-  flagship asserts they cover >=90%).
+  (the shares sum to the accounted fraction of wall time).
 
 Deliberately jax-free: the host-fallback planes (crypto/batch.py scalar
 route, the vote micro-batcher) count their batches here via

@@ -225,8 +225,8 @@ def write_dump(out_dir: str, node=None, loop=None, extras=None) -> str:
         traceback.print_exc(file=sys.stderr)
 
     # fleet-rollup snapshot, when a fleet scraper is running alongside this
-    # node (e2e runner / bench config 4 export TMTPU_FLEET_JSON and keep the
-    # file fresh): the cluster's view of the moment this node stalled
+    # node (the e2e runner exports TMTPU_FLEET_JSON and keeps the file
+    # fresh): the cluster's view of the moment this node stalled
     try:
         fleet = os.environ.get("TMTPU_FLEET_JSON")
         if fleet and os.path.exists(fleet):

@@ -7,8 +7,7 @@ Stdlib ``tomllib`` exists only from 3.11; this repo's TOML consumers
 full-line or trailing comments, and values that are quoted strings,
 booleans, integers, floats, or one-line lists thereof. When ``tomllib``
 is available it is used verbatim; otherwise :func:`loads` parses exactly
-that subset, so subprocess localnets (bench ``ingest``, the e2e runner,
-``cmd testnet``) run on 3.10 images instead of dying at import.
+that subset, so subprocess localnets (the e2e runner, ``cmd testnet``) run on 3.10 images instead of dying at import.
 """
 
 from __future__ import annotations

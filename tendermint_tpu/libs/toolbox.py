@@ -1,11 +1,11 @@
-"""Import repo tools/*.py modules from inside the package or bench.py.
+"""Import repo tools/*.py modules from inside the package, a tool or a test.
 
 The operator toolbox (tools/trace_summary.py, trace_merge.py,
 fleet_scrape.py, ...) is deliberately stdlib-only and lives OUTSIDE the
 package so it runs on boxes that can't import jax. Harness code that wants
-to reuse a tool in-process (bench.py breakdowns, the e2e runner's fleet
-scraper) imports it through this one helper instead of each hand-rolling
-the sys.path dance.
+to reuse a tool in-process (the e2e runner's fleet scraper, a test that
+needs a tool's fake) imports it through this one helper instead of each
+hand-rolling the sys.path dance.
 """
 
 from __future__ import annotations

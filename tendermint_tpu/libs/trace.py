@@ -16,7 +16,8 @@ chrome://tracing load directly.
 Disabled (the default) the hot path pays one attribute check: call sites
 guard with ``if tracer.enabled`` or rely on :meth:`Tracer.span` returning a
 shared no-op context manager — no event dict, no span object, no timestamp
-read is allocated. ``bench.py --trace-out`` and tests enable it explicitly.
+read is allocated. ``TMTPU_TRACE_OUT`` (cmd start) and tests enable it
+explicitly.
 
 The ring is a ``collections.deque(maxlen=...)``: appends are atomic under
 the GIL and old events fall off the front, so a long-running node can keep

@@ -29,8 +29,7 @@ ingest plane use:
   operator-supplied forged fork that only witness cross-check can catch.
 
 The planning math at the top (flush schedule, bisection skeleton, fan-out
-queue bounds) is pure stdlib with no package imports — loadable by file
-path from ``tools/lightserve_bench.py --self-test``; everything touching
+queue bounds) is pure stdlib with no package imports; everything touching
 crypto/types imports lazily inside methods.
 """
 
@@ -50,7 +49,6 @@ _MISS = object()
 
 
 # -- pure planning math ------------------------------------------------------
-# (stdlib-only: tools/lightserve_bench.py loads this file standalone)
 
 def bisection_skeleton(trusted_height: int, target_height: int,
                        cap: int = 64) -> List[int]:
@@ -82,7 +80,7 @@ def plan_flushes(arrivals: List[float], deadline_s: float,
     first request and closes when ``max_batch`` requests accumulate or
     ``deadline_s`` elapses, whichever first. Returns
     ``[(flush_time, batch_size)]`` — the pure spec ``VerifyCoalescer``
-    implements and the bench self-test checks."""
+    implements (tests/test_light_serve.py holds it to this)."""
     if max_batch < 1:
         raise ValueError("max_batch must be >= 1")
     if deadline_s < 0:

@@ -2,9 +2,9 @@
 per-sender mempool lanes, and async admission control.
 
 PR 11 built the measurement surface (libs/txlife.py lifecycle tracing,
-RPC/mempool telemetry, the open-loop ``ingest`` bench gated in
-bench_compare); this module is the fast path those gates were built to
-judge — the ROADMAP's "mempool + RPC built for millions of users" item.
+RPC/mempool telemetry, the open-loop load of tools/loadtime.py); this
+module is the fast path it was built to judge — the ROADMAP's "mempool +
+RPC built for millions of users" item.
 Three stages, front to back:
 
 **Async admission control** (:class:`IngestPipeline` +

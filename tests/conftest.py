@@ -172,7 +172,7 @@ def _no_build_by_accident(request, monkeypatch):
     the device (crypto/vote_batcher.py), on an executor thread that
     asyncio.run() then waits out: a cold build nobody asked for, in
     whichever test the timing picks. The stream seam stays real here: the
-    tools' stub kernels (tools/device_profile.py) sit under it."""
+    tools' stub kernels (tools/stub_kernels.py) sit under it."""
     if (os.path.basename(str(request.node.fspath)) in BUILD_FILES
             + ("test_chip_compile.py", "test_tpu_device.py")
             or "device_standin" in request.fixturenames):

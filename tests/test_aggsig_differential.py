@@ -177,6 +177,14 @@ def test_sub_quorum_rejected_by_both(rigs):
                             timestamp_ns=1_700_000_000_000_000_000)
     _rejects(lambda: bl.val_set.verify_commit(bl.chain_id, BID, HEIGHT, c_bl),
              ErrNotEnoughVotingPowerSigned)
+    # the FULL set's aggregate under that sub-quorum bitmap: whichever
+    # check sees it first (pairing or tally), it is a rejection
+    full = bl.make_commit(set(range(N)))
+    c_mis = AggregatedCommit(HEIGHT, 0, BID, [], signers=signers,
+                             agg_sig=full.agg_sig,
+                             timestamp_ns=full.timestamp_ns)
+    _rejects(lambda: bl.val_set.verify_commit(bl.chain_id, BID, HEIGHT,
+                                              c_mis))
 
 
 def test_duplicate_signer_rejected_by_both(rigs):

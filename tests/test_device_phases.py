@@ -1,8 +1,8 @@
 """Device-plane phase telemetry (crypto/phases.py + the ed25519_jax
 dispatcher wiring): per-segment pack/dispatch/fetch stamps tile the segment
 span exactly, host-routed batches count with zero device phases, the live
-plane's flushes land with plane="live", height tags ride the seg_* tracer
-spans, and the device_profile PROFILE JSON validates against its own schema.
+plane's flushes land with plane="live", and height tags ride the seg_*
+tracer spans.
 The device seam is conftest's ``device_standin``: no program is built here
 (the mesh's per-device series are read where the mesh program is built,
 tests/test_sharded_verify.py)."""
@@ -260,26 +260,3 @@ def test_stream_single_dispatch_also_records(device_metrics, accept_all):
     recs = phases.recent_segments()
     assert len(recs) == 1 and recs[0]["sigs"] == 200
     assert recs[0]["n_segs"] == 1
-
-
-def test_device_profile_schema_and_micro_sweep():
-    """The PROFILE JSON a real (stub-kernel) sweep emits validates against
-    the tool's own schema, and the sweep restores the module knobs."""
-    from tendermint_tpu.libs.toolbox import load_tool
-
-    dp = load_tool("device_profile")
-    old = (V.SEG_CHUNKS, V.SEG_MIN_SIGS, V._verify_kernel)
-    res = dp.run_sweep(sigs=256, chunks=[128], seg_chunks=[2],
-                       workload="synthetic", runs=1, seg_min_sigs=0)
-    assert (V.SEG_CHUNKS, V.SEG_MIN_SIGS, V._verify_kernel) == old
-    doc = dp.make_doc("sweep", {"sigs": 256}, res)
-    assert dp.validate_profile(doc) == []
-    row = doc["results"]["table"][0]
-    assert row["sigs_per_sec"] > 0 and row["segments"] >= 2
-    # a mutilated doc is rejected with a pointed error
-    del doc["results"]["table"][0]["sigs_per_sec"]
-    errs = dp.validate_profile(doc)
-    assert errs and "sigs_per_sec" in errs[0]
-    # and cross-kind required keys are enforced
-    bad = dp.make_doc("cost-model", {}, {"transfer": {}})
-    assert any("fixed_dispatch_ms" in e for e in dp.validate_profile(bad))

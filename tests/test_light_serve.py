@@ -69,6 +69,13 @@ def test_plan_flushes_deadline_and_size_triggers():
         [(0.001, 2), (0.007, 1)]
     # a gap larger than the deadline opens a new batch
     assert plan_flushes([0.0, 1.0], 0.005, 64) == [(0.005, 1), (1.005, 1)]
+    assert plan_flushes([], 0.005, 8) == []
+    # a dense burst closes on size, its tail on the deadline; every arrival
+    # is in exactly one batch
+    arrivals = [i * 0.00005 for i in range(100)] + [1.0]
+    plan = plan_flushes(arrivals, 0.002, 32)
+    assert sum(n for _, n in plan) == len(arrivals), plan
+    assert max(n for _, n in plan) == 32
     with pytest.raises(ValueError):
         plan_flushes([], 0.005, 0)
 

@@ -3,8 +3,8 @@ single-validator consensus chain served over a real aiohttp RPCServer:
 pre-planned sends land through broadcast_tx_sync, latency percentiles are
 recovered from committed blocks, and the /tx_timeline scrape shows the
 full rpc_received → committed stage chain with monotonic stamps — the
-acceptance criterion's measurement path, minus only the multi-process
-localnet bench.py --config ingest drives on full containers."""
+acceptance criterion's measurement path, minus only a multi-process
+localnet."""
 
 import asyncio
 import threading

@@ -16,7 +16,7 @@
   encoder and the dense packer's preimage bytes.
 
 Device work runs through shape-identical STUB kernels
-(tools/device_profile.install_stub_kernels): per-device-ordinal executables
+(tools/stub_kernels.install_stub_kernels): per-device-ordinal executables
 of the real ed25519 kernel take minutes to compile on CPU, and the stub
 verdict is a deterministic PER-ITEM function of the packed wire bytes — so
 verdict parity across sharding layouts exercises exactly the packing,
@@ -42,12 +42,12 @@ from tendermint_tpu.crypto.ed25519_jax import verify as V
 from tendermint_tpu.libs.faults import faults
 from tendermint_tpu.libs.toolbox import load_tool
 
-device_profile = load_tool("device_profile")
+install_stub_kernels = load_tool("stub_kernels").install_stub_kernels
 
 
 @pytest.fixture
 def stub_kernels():
-    restore = device_profile.install_stub_kernels(V)
+    restore = install_stub_kernels(V)
     yield
     restore()
 
@@ -103,21 +103,6 @@ def test_pool_disabled_by_env(monkeypatch):
     MD.reset_pool()
 
 
-def test_seg_chunks_from_cost_model():
-    doc = {"results": {"fixed_dispatch_ms": {"min": 80.0},
-                       "transfer": {"bandwidth_mbps": 10.0}}}
-    # 2048 sigs * 300 B ~ 0.59 MB -> ~59 ms/chunk; 9x80ms => ~13 chunks
-    sc = MD._seg_chunks_from_cost_model(doc)
-    assert 10 <= sc <= 16
-    # local chip: tiny fixed cost -> floor of 2
-    doc["results"]["fixed_dispatch_ms"]["min"] = 0.05
-    assert MD._seg_chunks_from_cost_model(doc) == 2
-    # bandwidth below the ladder's noise floor -> None (caller defaults)
-    doc["results"]["transfer"]["bandwidth_mbps"] = None
-    assert MD._seg_chunks_from_cost_model(doc) is None
-    assert MD._seg_chunks_from_cost_model({}) is None
-
-
 # -- verdict parity -----------------------------------------------------------
 
 def test_parity_mixed_batch_vs_single_device(stub_kernels):
@@ -148,7 +133,6 @@ def test_stream_entry_routes_through_pool(monkeypatch, stub_kernels,
     want = _single_device(pks, msgs, sigs)
     monkeypatch.setattr(V, "SEG_MIN_SIGS", 256)
     monkeypatch.setenv(MD.ENV_DEVICES, "4")
-    monkeypatch.setenv(MD.ENV_MIN_SIGS, "256")
     MD.reset_pool()
     try:
         got = V.batch_verify_stream(pks, msgs, sigs, chunk=V.LANE)
@@ -239,7 +223,6 @@ def test_all_lanes_sick_raises_and_batchverifier_survives(
 
     reset_lane_breakers()  # breakers tripped above; fresh pool health
     monkeypatch.setenv(MD.ENV_DEVICES, "3")
-    monkeypatch.setenv(MD.ENV_MIN_SIGS, "64")
     monkeypatch.setattr(V, "SEG_MIN_SIGS", 64)
     MD.reset_pool()
     try:

@@ -26,11 +26,8 @@ def test_discovery_sees_the_toolbox():
     assert res.returncode == 0, res.stderr
     tools = set(res.stdout.split())
     assert {"trace_summary.py", "trace_merge.py", "fleet_scrape.py",
-            "bench_compare.py", "chaos_matrix.py", "device_profile.py",
-            "loadtime.py", "churn.py", "crashmatrix.py",
-            "aggsig_bench.py", "soak.py"} <= tools
-    # the eight ad-hoc probe scripts device_profile.py consolidates are gone
-    assert not any(t.startswith(("relay_probe", "exp_10k")) for t in tools)
+            "chaos_matrix.py", "loadtime.py", "churn.py", "crashmatrix.py",
+            "soak.py", "quorum_loss.py", "execbench.py"} == tools
     assert "selfcheck.py" not in tools
 
 
@@ -46,5 +43,5 @@ def test_full_toolbox_passes():
     res = _run()
     assert res.returncode == 0, res.stdout + res.stderr
     lines = [l for l in res.stdout.splitlines() if l.startswith("PASS ")]
-    assert len(lines) >= 11, res.stdout
+    assert len(lines) == 10, res.stdout
     assert "FAIL" not in res.stdout

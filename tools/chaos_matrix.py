@@ -238,7 +238,7 @@ def cell_device_lane(seed: int) -> None:
     (``device.lane.<label>``) is armed against EXACTLY ONE device label,
     its breaker opens, the pool degrades to the healthy peers with
     byte-identical verdicts and zero dropped signatures, and a healed lane
-    rejoins. Shape-identical stub kernels (tools/device_profile) keep this
+    rejoins. Shape-identical stub kernels (tools/stub_kernels.py) keep this
     off the multi-minute per-ordinal CPU compiles of the real kernel."""
     import os
 
@@ -247,8 +247,8 @@ def cell_device_lane(seed: int) -> None:
     os.environ["TMTPU_DEVICE_BREAKER_THRESHOLD"] = "2"
     os.environ["TMTPU_DEVICE_BREAKER_COOLDOWN_S"] = "0.05"
 
-    import device_profile as DP
     import jax
+    import stub_kernels
 
     from tendermint_tpu.crypto.breaker import (
         CLOSED,
@@ -260,7 +260,7 @@ def cell_device_lane(seed: int) -> None:
     from tendermint_tpu.crypto.ed25519_jax import verify as V
     from tendermint_tpu.libs.faults import faults
 
-    restore = DP.install_stub_kernels(V)
+    restore = stub_kernels.install_stub_kernels(V)
     try:
         rng = np.random.default_rng(seed)
         n = 1280

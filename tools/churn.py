@@ -689,57 +689,6 @@ def run_flap(cycles: int = 3, seed: int = 1) -> dict:
     return asyncio.run(_flap_async(cycles, seed))
 
 
-async def _gossip_async(n: int, blocks: int, topology: str, degree: int,
-                        seed: int) -> dict:
-    net, nodes, _pvs, _genesis = await build_fleet(
-        n, topology=topology, degree=degree, seed=seed)
-    try:
-        await _wait_heights(list(nodes.values()), 2, timeout=300)
-        h0 = max(nd.height for nd in nodes.values())
-        t0 = time.monotonic()
-        wak0 = sum(nd.wakeups() for nd in nodes.values())
-        ec0 = [nd.encode_cache() for nd in nodes.values()]
-        await _wait_heights(list(nodes.values()), h0 + blocks,
-                            timeout=60.0 * blocks)
-        elapsed = max(0.001, time.monotonic() - t0)
-        wak = sum(nd.wakeups() for nd in nodes.values()) - wak0
-        hits = sum(nd.encode_cache()[0] for nd in nodes.values()) \
-            - sum(h for h, _ in ec0)
-        miss = sum(nd.encode_cache()[1] for nd in nodes.values()) \
-            - sum(m for _, m in ec0)
-    finally:
-        for nd in nodes.values():
-            try:
-                await nd.stop()
-            except Exception:
-                pass
-    links = max(1, len(net.links))
-    return {
-        "n_nodes": n, "topology": topology, "directed_links": links,
-        "blocks": blocks, "elapsed_s": round(elapsed, 2),
-        # the rate is the scaling evidence (fleet_scrape's convention:
-        # wakeup deltas over wall time per directed link) — per-BLOCK
-        # numbers mislead at scale because block cadence slows with N
-        "wakeups_per_link_per_s": round(wak / links / elapsed, 3),
-        "wakeups_total_per_s": round(wak / elapsed, 3),
-        "wakeups_per_link_per_block": round(wak / links / blocks, 3),
-        "encode_cache_hit_ratio": round(hits / max(1.0, hits + miss), 3),
-    }
-
-
-def measure_gossip(n: int = 8, blocks: int = 3, topology: str = "sparse",
-                   degree: int = 4, seed: int = 1) -> dict:
-    """Gossip cost at size N: a static sparse fleet commits ``blocks``
-    heights; reports the wakeup RATE per directed peer-link (plus the
-    wire-encode cache hit ratio) — the bench's sublinearity evidence at
-    N=8/16/32: a flat-or-falling per-link rate means each node's gossip
-    cost tracks its DEGREE, not the fleet size."""
-    import asyncio
-
-    os.environ.setdefault("TMTPU_BATCH_BACKEND", "host")
-    return asyncio.run(_gossip_async(n, blocks, topology, degree, seed))
-
-
 def run_churn(n_nodes: int = 8, intervals: int = 2, seed: int = 1,
               topology: str = "full_mesh", degree: int = 3,
               rate: float = 10.0) -> dict:
@@ -764,7 +713,7 @@ def schedule_fingerprint(report: dict) -> dict:
             "plan": report["plan"]}
 
 
-# -- self-test (stdlib-only: plan + schema, the net runs live in chaos/bench) -
+# -- self-test (stdlib-only: plan + schema, the net runs live in chaos) ------
 
 def self_test() -> int:
     # plan determinism + shape

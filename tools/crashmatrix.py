@@ -420,7 +420,7 @@ async def _run_async(seed: int, boundaries, home_root: str) -> dict:
     from tendermint_tpu.p2p import InProcNetwork
 
     # via the toolbox helper, not a bare import: callers that loaded THIS
-    # module through load_tool (bench --config crash) have already popped
+    # module through load_tool (chaos_matrix, the tests) have already popped
     # tools/ back off sys.path by the time the run executes
     churn = load_tool("churn")
 

@@ -11,15 +11,14 @@ endpoints on an interval and emits cluster rollups:
   lagging node,
 * gossip wakeups-per-peer-link (sum of wakeup deltas / directed links),
 
-as JSON consumed by bench config 4 and the e2e runner (which also exports
-the path via TMTPU_FLEET_JSON so node debugdump bundles can include the
+as JSON consumed by the e2e runner (which also exports the path via TMTPU_FLEET_JSON so node debugdump bundles can include the
 snapshot).
 
     python tools/fleet_scrape.py --ports 28664,28665,28666,28667 \
         --duration 30 --interval 2 --out fleet.json
     python tools/fleet_scrape.py --self-test
 
-Stdlib-only on purpose: it runs inside bench/e2e harnesses and on boxes
+Stdlib-only on purpose: it runs inside the e2e harness and on boxes
 that can't import the package.
 """
 
@@ -238,7 +237,7 @@ class FleetScraper:
         # ingestion-plane rollups (mempool + RPC series): counter deltas
         # summed across nodes over the scrape window — the cluster's tx
         # admission/rejection rate and RPC traffic, the fleet view the
-        # ingest bench and the mempool_full chaos cell read
+        # mempool_full chaos cell reads
         admitted = counter_delta(
             self._series_name("mempool_admitted_txs_total"))
         rejected = counter_delta(self._series_name("mempool_failed_txs"))

@@ -12,7 +12,7 @@ time, embedded in the tx itself and recovered from committed blocks.)
     # recover per-tx latency from committed blocks (+ optional scrapes)
     python tools/loadtime.py report --endpoint http://127.0.0.1:26657 \
         --metrics-endpoint http://127.0.0.1:26660/metrics
-    # both, one shot (what bench.py --config ingest drives)
+    # both, one shot
     python tools/loadtime.py run --endpoint http://127.0.0.1:26657
     python tools/loadtime.py --self-test
 
@@ -305,8 +305,7 @@ def summarize_timeline(doc: dict) -> dict:
             stage_counts[s] = stage_counts.get(s, 0) + 1
         if "rpc_received" in marks and "mempool_admitted" in marks:
             # admission latency: RPC front door -> lane insertion, the
-            # in-node CheckTx-path cost the ingest bench gates as
-            # localnet_4node_ingest_checktx_p99_s
+            # in-node CheckTx-path cost
             admission_s.append(
                 max(0.0, marks["mempool_admitted"] - marks["rpc_received"]))
         if rec.get("terminal") == "committed":
@@ -375,7 +374,7 @@ def summarize_rejections(metrics: Dict[str, float]) -> Dict[str, dict]:
 def report_doc(endpoint: str, metrics_endpoint: Optional[str] = None,
                max_blocks: int = 2000) -> dict:
     """Walk committed blocks + scrape the observability surfaces; the dict
-    bench.py --config ingest turns into its two gated metric lines."""
+    ``report`` prints."""
     status = _rpc_get(endpoint, "status")
     latest = int(status["sync_info"]["latest_block_height"])
     base = max(1, int(status["sync_info"]["earliest_block_height"] or 1),
@@ -393,7 +392,7 @@ def report_doc(endpoint: str, metrics_endpoint: Optional[str] = None,
             # carrying harness txs)
             doc["txs_per_sec"] = round(n_txs / span_s, 3)
         # a single-block burst has NO window: emitting the raw count as a
-        # rate would poison the higher-better bench gate — leave the key
+        # rate would read as throughput — leave the key
         # absent so callers fail loud instead of recording a fiction
         doc["latency_s"] = {k: round(v, 4)
                             for k, v in percentiles(lats).items()}

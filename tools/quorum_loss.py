@@ -20,8 +20,8 @@ makes that whole contract a seeded, asserted, gated scenario:
   freezes, assert a survivor's ConsensusWatchdog classifies the episode
   ``quorum_lost`` with the isolated validators absent from the round's
   vote bitmaps, assert zero equivocations observed anywhere, then
-  ``heal()`` exactly the cut and clock heal→next-commit (the worst
-  window feeds the gated ``inproc_quorumloss_recover_s`` bench row);
+  ``heal()`` exactly the cut and clock heal→next-commit (the report's
+  ``recover_max_s`` is the worst window);
 * ``run_wan`` — the same fleet under the ``wan`` link profile
   (seeded base+jitter latency, light loss, reorder on every directed
   link), commit throughput on the clock (the gated

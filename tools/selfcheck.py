@@ -1,17 +1,17 @@
 """Run every tools/*.py --self-test in a fresh subprocess; fail loud.
 
 The tools directory is the operator's toolbox (trace_summary, trace_merge,
-fleet_scrape, bench_compare, chaos_matrix, device_profile, loadtime,
-churn, crashmatrix, aggsig_bench) and each carries
-a built-in --self-test. This runner discovers them (any tools/*.py whose source
-mentions --self-test) and executes each in a subprocess — argument
+fleet_scrape, chaos_matrix, loadtime, churn, crashmatrix, soak,
+quorum_loss, execbench) and each carries a built-in --self-test. This
+runner discovers them (any tools/*.py whose source mentions --self-test)
+and executes each in a subprocess — argument
 parsing, imports, and exit codes included — so a refactor that rots a tool
 is caught by pytest (tests/test_tools_selfcheck.py), not by the first
 operator who needs it during an incident:
 
     python tools/selfcheck.py            # run them all
     python tools/selfcheck.py --list     # show what would run
-    python tools/selfcheck.py --only trace_merge,bench_compare
+    python tools/selfcheck.py --only trace_merge,loadtime
     python tools/selfcheck.py --self-test
 
 Stdlib-only; subprocesses inherit a CPU-pinned JAX env so a tool that
@@ -82,11 +82,9 @@ def self_test() -> int:
     # the whole point is catching rot in the known toolbox — if discovery
     # stops seeing these, THIS tool rotted
     for expected in ("trace_summary.py", "trace_merge.py",
-                     "fleet_scrape.py", "bench_compare.py",
-                     "chaos_matrix.py", "device_profile.py",
+                     "fleet_scrape.py", "chaos_matrix.py",
                      "loadtime.py", "churn.py", "crashmatrix.py",
-                     "aggsig_bench.py", "soak.py",
-                     "lightserve_bench.py"):
+                     "soak.py", "quorum_loss.py", "execbench.py"):
         assert expected in tools, (expected, tools)
     assert os.path.basename(__file__) not in tools  # no recursion
     # prove the runner distinguishes pass from fail without running the

@@ -722,7 +722,7 @@ async def _run_async(n_nodes: int, seed: int, duration_s: float,
 
     breaches = slo.attribute_all(engine.evaluate(), armed_windows,
                                  stage_windows, total_span=duration_s)
-    # headline observations for bench rows: one number each, derived from
+    # headline observations for the report: one number each, derived from
     # the same streams the SLO engine judged (not a parallel measurement)
     lat_vals = [v for _, v, _ in engine._streams.get("commit_latency", [])]
     caught_vals = [v for _, v, _ in engine._streams.get("caughtup", [])]
@@ -781,58 +781,6 @@ def _write_report(path: str, doc: dict) -> str:
             pass
         raise
     return path
-
-
-def history_rows(report: dict) -> list:
-    """The game day reduced to its gated bench rows (same metric names
-    and units as ``bench.py --config soak``) for the cross-run trend
-    file. Pure: derives everything from the report dict."""
-    sl = report["slo"]
-    rows = [{"metric": "inproc_soak_slo_breaches",
-             "value": float(len(sl["breaches"])), "unit": "breaches",
-             "unattributed": sl["unattributed"]}]
-    obs = report.get("observed", {})
-    if obs.get("commit_samples"):
-        rows.append({"metric": "inproc_soak_commit_p99_s",
-                     "value": float(obs["commit_p99_s"]), "unit": "s",
-                     "commit_samples": obs["commit_samples"]})
-    else:
-        rows.append({"metric": "inproc_soak_commit_p99_s",
-                     "value": 0.0, "unit": "error",
-                     "error": "no commit latency samples observed"})
-    planned = {ev["plane"] for ev in report["plan"]["events"]}
-    recoveries = [k["kill_to_caughtup_s"] for k in report.get("kills", [])
-                  if k.get("kill_to_caughtup_s") is not None]
-    if recoveries:
-        rows.append({"metric": "inproc_soak_kill_caughtup_s",
-                     "value": float(max(recoveries)), "unit": "s",
-                     "kills": len(report["kills"])})
-    elif "crash" in planned:
-        # the crash plane armed but never completed a kill->rejoin
-        # cycle: an errored row the trend gate must see, not a silently
-        # absent one (small fleets with NO crash plane omit the row —
-        # same-shape runs stay comparable)
-        rows.append({"metric": "inproc_soak_kill_caughtup_s",
-                     "value": 0.0, "unit": "error",
-                     "error": "no completed kill->rejoin cycle"})
-    return rows
-
-
-def append_history(path: str, report: dict, label=None) -> dict:
-    """Append ONE line to the cross-run trend file (JSONL — the format
-    tools/bench_compare.py --history gates): {"label", "metrics"}."""
-    entry = {
-        "label": label or (f"seed{report['seed']}"
-                           f"-n{report['n_nodes']}"
-                           f"-{int(report['duration_s'])}s"),
-        "seed": report["seed"],
-        "schedule_fingerprint": report.get("schedule_fingerprint"),
-        "breach_fingerprint": report.get("breach_fingerprint"),
-        "metrics": history_rows(report),
-    }
-    with open(path, "a") as f:
-        f.write(json.dumps(entry, default=str) + "\n")
-    return entry
 
 
 def run_soak(n_nodes: int = 8, seed: int = 1, duration_s: float = 120.0,
@@ -989,58 +937,8 @@ def self_test() -> int:
            "observed": 1.1, "attribution": {"plane": "p", "stage": "s"}}]
     assert slo.breach_fingerprint(b1) == slo.breach_fingerprint(b2)
 
-    # cross-run trend file: history_rows mirrors bench.py's gated soak
-    # rows (names AND units), append_history writes one JSONL line per
-    # run, and the file round-trips through bench_compare --history
-    import tempfile
-
-    fake = {"seed": 3, "n_nodes": 6, "duration_s": 60.0,
-            "plan": {"events": [{"plane": "corrupt"}, {"plane": "crash"}]},
-            "observed": {"commit_p99_s": 1.25, "commit_samples": 40},
-            "kills": [{"kill_to_caughtup_s": 12.5}],
-            "slo": {"breaches": [{"objective": "x"}], "unattributed": 1},
-            "schedule_fingerprint": "s", "breach_fingerprint": "b"}
-    rows = {r["metric"]: r for r in history_rows(fake)}
-    assert rows["inproc_soak_slo_breaches"]["value"] == 1.0
-    assert rows["inproc_soak_slo_breaches"]["unit"] == "breaches"
-    assert rows["inproc_soak_commit_p99_s"] \
-        == {"metric": "inproc_soak_commit_p99_s", "value": 1.25,
-            "unit": "s", "commit_samples": 40}
-    assert rows["inproc_soak_kill_caughtup_s"]["value"] == 12.5
-    # armed-but-unfinished crash plane -> errored row, never absent
-    stuck = dict(fake, kills=[{"fired": False}])
-    rows = {r["metric"]: r for r in history_rows(stuck)}
-    assert rows["inproc_soak_kill_caughtup_s"]["unit"] == "error"
-    # no crash plane planned (small fleet) -> the row is legitimately out
-    small = dict(fake, plan={"events": [{"plane": "corrupt"}]}, kills=[])
-    assert "inproc_soak_kill_caughtup_s" not in {
-        r["metric"] for r in history_rows(small)}
-    d = tempfile.mkdtemp(prefix="soak-selftest-")
-    try:
-        hist = os.path.join(d, "trend.jsonl")
-        e1 = append_history(hist, fake)
-        assert e1["label"] == "seed3-n6-60s"
-        worse = dict(fake, slo={"breaches": [{}, {}, {}, {}],
-                                "unattributed": 0})
-        append_history(hist, worse, label="worse")
-        with open(hist) as f:
-            entries = [json.loads(line) for line in f]
-        assert [e["label"] for e in entries] == ["seed3-n6-60s", "worse"]
-        assert all(e["metrics"] for e in entries)
-        import bench_compare
-        labels, runs = bench_compare.load_history(hist)
-        assert labels == ["seed3-n6-60s", "worse"]
-        verdict = {r["metric"]: r for r in bench_compare.compare(
-            runs[-2], runs[-1], {})}
-        assert verdict["inproc_soak_slo_breaches"]["status"] == "regressed"
-    finally:
-        import shutil
-
-        shutil.rmtree(d, ignore_errors=True)
-
     print("soak self-test OK (spec grammar, window math, attribution, "
-          "plan determinism, injected-regression + leak outcomes, "
-          "cross-run trend rows)")
+          "plan determinism, injected-regression + leak outcomes)")
     return 0
 
 
@@ -1063,10 +961,6 @@ def main(argv=None) -> int:
                     help="SLO spec file (default: libs/slo.py DEFAULT_SPEC)")
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="report path (default ./soak_report.json)")
-    ap.add_argument("--history", default=None, metavar="PATH",
-                    help="append this run's gated rows to a cross-run "
-                         "JSONL trend file (gate the trajectory with "
-                         "tools/bench_compare.py --history PATH)")
     ap.add_argument("--seeds", default="1,2",
                     help="seeds for --verify-determinism")
     ap.add_argument("--json", action="store_true")
@@ -1098,10 +992,6 @@ def main(argv=None) -> int:
         spec_text=spec_text, out=args.out,
         sample_interval=args.sample_interval, topology=args.topology,
         degree=args.degree)
-    if args.history:
-        entry = append_history(args.history, report)
-        print(f"history += {entry['label']} -> {args.history} "
-              f"({len(entry['metrics'])} rows)")
     if args.json:
         print(json.dumps(report, indent=2, default=str))
     else:

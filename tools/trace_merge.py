@@ -307,7 +307,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("traces", nargs="*",
                     help="per-node Chrome trace-event JSONs "
-                         "(TMTPU_TRACE_OUT / bench --trace-out output)")
+                         "(TMTPU_TRACE_OUT output)")
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="write the merged Perfetto-loadable trace here")
     ap.add_argument("--json", action="store_true",

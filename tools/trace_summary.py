@@ -1,10 +1,10 @@
-"""Summarize a Chrome trace-event JSON (libs/trace.py / bench.py --trace-out).
+"""Summarize a Chrome trace-event JSON (libs/trace.py; TMTPU_TRACE_OUT).
 
-Prints per-span count / total / p50 / p99 so a bench trace answers "where
+Prints per-span count / total / p50 / p99 so a trace answers "where
 did the window go" without opening Perfetto:
 
-    python tools/trace_summary.py /tmp/bench-trace.json
-    python tools/trace_summary.py --json /tmp/bench-trace.json   # machine-readable
+    python tools/trace_summary.py /tmp/trace.json
+    python tools/trace_summary.py --json /tmp/trace.json   # machine-readable
     python tools/trace_summary.py trace-*.json --node-prefix     # cluster view
     python tools/trace_summary.py --self-test                    # CI guard
 
@@ -126,7 +126,7 @@ def render(summary: Dict[str, dict]) -> str:
 
 def self_test() -> int:
     """Round-trip a synthetic trace through a temp file: the format this
-    tool parses is exactly what libs/trace.py and bench.py emit. Returns 0
+    tool parses is exactly what libs/trace.py emits. Returns 0
     on success (CI runs this under pytest so the tool can't rot)."""
     import os
     import tempfile
