@@ -19,7 +19,8 @@ Reading support is the mirror image, used for storage/wire decoding.
 
 from __future__ import annotations
 
-from typing import Dict, Iterator, List, Tuple, Union
+from itertools import chain
+from typing import Dict, Iterator, List, Sequence, Tuple, Union
 
 WIRE_VARINT = 0
 WIRE_FIXED64 = 1
@@ -104,6 +105,31 @@ def timestamp(ns: int) -> bytes:
     w.varint(1, seconds)
     w.varint(2, nanos)
     return w.finish()
+
+
+class PieceTable(dict):
+    """key -> ``build(key)``, built the first time the key is asked for
+    and kept: the constant pieces of a one-pass encoder (a prefix per body
+    length, a timestamp message per distinct timestamp), looked up per row
+    without leaving C."""
+
+    def __init__(self, build) -> None:
+        self._build = build
+
+    def __missing__(self, key) -> bytes:
+        piece = self[key] = self._build(key)
+        return piece
+
+
+def repeated_message(field: int, bodies: Sequence[bytes]) -> bytes:
+    """What ``Writer.message(field, body)`` emits for each of ``bodies`` in
+    turn, as one ``bytes``: a thousand-row repeated field in one pass (one
+    prefix per distinct body length, one join) instead of a Writer call and
+    a buffer append per row. Holds the interpreter lock throughout."""
+    key = tag(field, WIRE_BYTES)
+    frames = PieceTable(lambda n: key + encode_varint(n))
+    return b"".join(chain.from_iterable(
+        zip(map(frames.__getitem__, map(len, bodies)), bodies)))
 
 
 def length_delimited(body: bytes) -> bytes:

@@ -15,7 +15,7 @@ from ..abci import types as abci
 from ..abci.client import Client
 from ..store import BlockStore
 from ..types import ConsensusParams, ValidatorSet
-from ..types.basic import BlockID, BlockIDFlag
+from ..types.basic import BlockID, BlockIDFlag, encode_stats
 from ..types.block import Block, Commit
 from ..types.evidence import Evidence
 from ..types.part_set import PartSet
@@ -135,8 +135,18 @@ class BlockExecutor:
 
         # exception-safe span: a rejected block must still leave its event
         with _tracer.span("apply_block", height=block.header.height,
-                          n_txs=len(block.data.txs)):
-            return self._apply_block_inner(state, block_id, block)
+                          n_txs=len(block.data.txs)) as span:
+            before = dict(encode_stats)
+            out = self._apply_block_inner(state, block_id, block)
+            # encode-once engagement: the LastCommit's table found kept
+            # (stage A or save_block built it), one validator set encoded
+            # (the new next_validators)
+            span.set(
+                commit_reused=encode_stats["commit_tables_reused"]
+                - before["commit_tables_reused"],
+                valsets_built=encode_stats["valset_encodes_built"]
+                - before["valset_encodes_built"])
+            return out
 
     def _apply_block_inner(self, state: State, block_id: BlockID,
                            block: Block) -> Tuple[State, int]:

@@ -14,7 +14,8 @@ from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
 from ..libs.db import DB, BufferedDB
-from ..types.basic import BlockID
+from ..libs.trace import tracer
+from ..types.basic import BlockID, encode_stats
 from ..types.block import Block, BlockMeta, Commit
 from ..types.part_set import Part, PartSet
 
@@ -151,7 +152,8 @@ class BlockStore:
     def save_block(self, block: Block, block_parts: PartSet, seen_commit: Commit) -> None:
         """(store/store.go:332 SaveBlock)"""
         height = block.header.height
-        with self._mtx:
+        with tracer.span("save_block", height=height) as span, self._mtx:
+            before = dict(encode_stats)
             expected = self._height + 1
             if self._height > 0 and height != expected:
                 raise ValueError(f"BlockStore can only save contiguous blocks. Wanted {expected}, got {height}")
@@ -176,6 +178,13 @@ class BlockStore:
                 self._base = height
             self._height = height
             self._save_state()
+            # encode-once engagement: of the two commits written, the
+            # block's LastCommit is found kept, the seen commit built here
+            span.set(
+                commit_built=encode_stats["commit_tables_built"]
+                - before["commit_tables_built"],
+                commit_reused=encode_stats["commit_tables_reused"]
+                - before["commit_tables_reused"])
 
     def save_seen_commit(self, height: int, commit: Commit) -> None:
         self._db.set(_seen_commit_key(height), commit.encode())

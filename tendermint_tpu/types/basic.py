@@ -27,6 +27,19 @@ class SignedMsgType(IntEnum):
     PROPOSAL = 32
 
 
+# encode-once observability (crypto/batch.py ``stats``' idiom: cumulative,
+# cheap ints only): how often a Commit's row table and a ValidatorSet's
+# encoded rows were built against how often a reader found them kept, and
+# how many commit rows were not of the regular shape and went through
+# CommitSig.encode. A sound sync builds one table a distinct commit and one
+# set of rows a height; every other reader reuses.
+encode_stats = {
+    "commit_tables_built": 0, "commit_tables_reused": 0,
+    "commit_rows_by_row": 0,
+    "valset_encodes_built": 0, "valset_encodes_reused": 0,
+}
+
+
 class BlockIDFlag(IntEnum):
     UNKNOWN = 0
     ABSENT = 1

@@ -20,7 +20,10 @@ from .. import crypto
 from ..crypto import merkle, schemes
 from ..libs import protowire as pw
 from ..libs.bits import BitArray
-from .basic import BlockID, BlockIDFlag, PartSetHeader, SignedMsgType, ZERO_TIME_NS
+from .basic import (
+    BlockID, BlockIDFlag, PartSetHeader, SignedMsgType, ZERO_TIME_NS,
+    encode_stats,
+)
 from .canonical import vote_sign_bytes, vote_sign_bytes_table
 from .tx import txs_hash
 from .vote import MAX_SIGNATURE_SIZE, Vote
@@ -289,13 +292,66 @@ class CommitSig:
 _FLAG_OF = attrgetter("block_id_flag")
 
 
+def _row_head(flag) -> bytes:
+    """A regular row's bytes before the address: the flag field as
+    CommitSig.encode writes it, then the address field's tag and length
+    (20)."""
+    w = pw.Writer()
+    w.varint(1, int(flag))
+    return w.finish() + b"\x12\x14"
+
+
+def _row_mid(timestamp_ns: int) -> bytes:
+    """A regular row's bytes between address and signature: the timestamp
+    message, then the signature field's tag and length (64)."""
+    w = pw.Writer()
+    w.message(3, pw.timestamp(timestamp_ns))
+    return w.finish() + b"\x22\x40"
+
+
+class _CommitWire:
+    """One Commit's rows on the wire, built once, in one pass: ``leaves``
+    (each CommitSig's encoding: what Commit.hash merkle-izes), ``framed``
+    (the same rows as field 4 of the Commit message: Commit.encode's body
+    after its three head fields) and ``root`` (the merkle root over the
+    leaves, which the first Commit.hash puts in their place).
+
+    A regular row (a 20-byte address and a 64-byte signature, whatever its
+    flag and timestamp) is ``08 flag | 12 14 addr | 1a len ts | 22 40 sig``,
+    concatenated from pieces built once per distinct flag and timestamp
+    (one pw.timestamp per distinct value of a commit, not one per row);
+    any other row (absent, an address or signature of another length) is
+    CommitSig.encode's, which stays the one definition of a row. Which of
+    the two a row takes follows from what it holds alone."""
+
+    __slots__ = ("rows", "leaves", "framed", "root")
+
+    def __init__(self, rows: List[CommitSig]):
+        heads, mids = pw.PieceTable(_row_head), pw.PieceTable(_row_mid)
+        odd: List[CommitSig] = []
+
+        def by_row(cs: CommitSig) -> bytes:
+            odd.append(cs)
+            return cs.encode()
+
+        self.rows = list(rows)  # the objects encoded, to tell a later list by
+        self.leaves = [
+            heads[cs.block_id_flag] + cs.validator_address
+            + mids[cs.timestamp_ns] + cs.signature
+            if len(cs.validator_address) == 20 and len(cs.signature) == 64
+            else by_row(cs) for cs in self.rows]
+        self.framed = pw.repeated_message(4, self.leaves)
+        self.root: Optional[bytes] = None
+        encode_stats["commit_tables_built"] += 1
+        encode_stats["commit_rows_by_row"] += len(odd)
+
+
 @dataclass
 class Commit:
     height: int
     round: int
     block_id: BlockID
     signatures: List[CommitSig] = field(default_factory=list)
-    _hash: Optional[bytes] = field(default=None, repr=False, compare=False)
 
     def get_vote(self, val_idx: int) -> Vote:
         cs = self.signatures[val_idx]
@@ -390,10 +446,31 @@ class Commit:
     def size(self) -> int:
         return len(self.signatures)
 
+    def _wire(self) -> _CommitWire:
+        """The commit's row table (_CommitWire), built by whichever of
+        ``encode`` and ``hash`` comes first and read by both from then on:
+        one memo, under the rule the sign-bytes table above lives by
+        (commits are immutable once built). What invalidates it:
+        ``signatures`` no longer holding the same row objects in the same
+        order (another list, a row appended, dropped or replaced). A write
+        INTO a row object after the first encode or hash is not seen, as
+        the hash memo never saw one. height, round and block_id are not in
+        the table: ``encode`` writes them anew every call."""
+        rows = self.signatures
+        wire = self.__dict__.get("_wire_memo")
+        if wire is None or wire.rows != rows:
+            wire = self.__dict__["_wire_memo"] = _CommitWire(rows)
+        else:
+            encode_stats["commit_tables_reused"] += 1
+        return wire
+
     def hash(self) -> bytes:
-        if self._hash is None:
-            self._hash = merkle.hash_from_byte_slices([cs.encode() for cs in self.signatures])
-        return self._hash
+        """Merkle root over the CommitSig encodings (block.go:894)."""
+        wire = self._wire()
+        if wire.root is None:
+            wire.root = merkle.hash_from_byte_slices(wire.leaves)
+            wire.leaves = None  # the root is all a later reader asks for
+        return wire.root
 
     def validate_basic(self) -> None:
         if self.height < 0:
@@ -416,9 +493,7 @@ class Commit:
         w.varint(1, self.height)
         w.varint(2, self.round)
         w.message(3, self.block_id.encode())
-        for cs in self.signatures:
-            w.message(4, cs.encode())
-        return w.finish()
+        return w.finish() + self._wire().framed
 
     @staticmethod
     def decode(data: bytes) -> "Commit":
@@ -470,6 +545,7 @@ class AggregatedCommit(Commit):
     signers: BitArray = field(default_factory=lambda: BitArray(0))
     agg_sig: bytes = b""
     timestamp_ns: int = 0
+    _hash: Optional[bytes] = field(default=None, repr=False, compare=False)
 
     def size(self) -> int:
         return self.signers.size()

@@ -14,6 +14,8 @@ from ..libs import protowire as pw
 MAX_TOTAL_VOTING_POWER = (2**63 - 1) // 8  # types/validator_set.go:25
 PRIORITY_WINDOW_SIZE_FACTOR = 2  # types/validator_set.go:30
 
+PRIORITY_TAG = pw.tag(4, pw.WIRE_VARINT)  # Validator.proposer_priority
+
 INT64_MAX = 2**63 - 1
 INT64_MIN = -(2**63)
 
@@ -105,14 +107,11 @@ class Validator:
         w.varint(2, self.voting_power)
         return w.finish()
 
-    def encode(self) -> bytes:
-        """Full Validator proto (validator.proto:15-20) for wire/storage.
-
-        The address/pubkey/power prefix is immutable for a validator's
-        lifetime and cached; only the proposer-priority varint (which
-        rotates every height) is re-encoded. State persistence encodes
-        whole 1000-validator sets several times per block, so this is a
-        measured hot path, not speculation."""
+    def encode_prefix(self) -> bytes:
+        """The address, pubkey and power fields of ``encode``: all of it
+        but the proposer priority. Immutable for a validator's lifetime
+        and cached: state persistence encodes whole 1000-validator sets
+        every block, so this is a measured hot path, not speculation."""
         # hold the pub_key OBJECT and compare with `is`: keying on
         # id(self.pub_key) is an id-recycling hazard — a replaced key object
         # can land on the freed key's address and silently serve the old
@@ -127,10 +126,16 @@ class Validator:
             w.varint(3, self.voting_power)
             cached = (self.pub_key, self.voting_power, w.finish())
             self.__dict__["_enc_prefix"] = cached
+        return cached[2]
+
+    def encode(self) -> bytes:
+        """Full Validator proto (validator.proto:15-20) for wire/storage:
+        the cached prefix and the proposer-priority varint, which rotates
+        every height."""
         pp = self.proposer_priority
         if pp == 0:  # proto3 zero omission, like Writer.varint
-            return cached[2]
-        return cached[2] + pw.tag(4, pw.WIRE_VARINT) + pw.encode_varint(pp)
+            return self.encode_prefix()
+        return self.encode_prefix() + PRIORITY_TAG + pw.encode_varint(pp)
 
     @staticmethod
     def decode(data: bytes) -> "Validator":

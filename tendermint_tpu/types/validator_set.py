@@ -14,13 +14,15 @@ powers): no Python runs per row of a large commit.
 
 from __future__ import annotations
 
+from operator import attrgetter
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..crypto import Ed25519PubKey
 from ..crypto.batch import STREAM_CHUNK, BatchVerifier
-from .basic import BlockID, BlockIDFlag
+from ..libs import protowire as pw
+from .basic import BlockID, BlockIDFlag, encode_stats
 from .errors import (
     ErrInvalidCommitHeight,
     ErrInvalidCommitSignatures,
@@ -29,6 +31,7 @@ from .errors import (
 )
 from .validator import (
     MAX_TOTAL_VOTING_POWER,
+    PRIORITY_TAG,
     PRIORITY_WINDOW_SIZE_FACTOR,
     Validator,
     safe_add_clip,
@@ -58,6 +61,9 @@ def _observe_aggregated_wire_size(commit) -> None:
         m.aggregated_commit_bytes.observe(float(len(commit.encode())))
     except Exception:
         pass
+
+
+_PRIORITY_OF = attrgetter("proposer_priority")
 
 
 def _by_voting_power(v: Validator):
@@ -149,17 +155,17 @@ class ValidatorSet:
         vs.validators = [v.copy() for v in self.validators]
         vs.proposer = self.proposer
         vs._total_voting_power = self._total_voting_power
-        # membership, keys and powers are identical, so the merkle hash and
-        # the verify arrays carry over (priorities are part of neither);
-        # re-keyed to the copy's own list + mutation count so later
+        # membership, keys and powers are identical, so the merkle hash,
+        # the verify arrays and the rows' encoded prefixes carry over
+        # (priorities are part of none), and so do the encoded rows (which
+        # hold the priorities they were built from, compared on every
+        # use); re-keyed to the copy's own list + mutation count so later
         # structural mutations invalidate normally
-        for name in ("_hash_cache", "_verify_cache"):
-            cache = self.__dict__.get(name)
-            if cache is not None and cache[0] is self.validators \
-                    and cache[1] == self._mutations \
-                    and cache[2] == len(self.validators):
-                vs.__dict__[name] = (vs.validators, vs._mutations,
-                                     len(vs.validators), cache[3])
+        for name in ("_hash_cache", "_verify_cache", "_prefix_cache",
+                     "_rows_cache"):
+            value = self._kept(name)
+            if value is not None:
+                vs._keep(name, value)
         return vs
 
     def _bump_mutations(self) -> None:
@@ -168,18 +174,26 @@ class ValidatorSet:
         mutation that preserves list identity and length still invalidates."""
         self._mutations += 1
 
-    def _memo(self, name: str, build):
-        """``build()``'s result, kept in ``__dict__[name]`` for as long as
-        the validators list is the same object of the same length and no
-        structural mutator has bumped ``_mutations``."""
+    def _kept(self, name: str):
+        """What ``_keep(name, ...)`` kept, or None once the validators list
+        is another object or of another length, or a structural mutator
+        has bumped ``_mutations``."""
         cache = self.__dict__.get(name)
         if (cache is None or cache[0] is not self.validators
                 or cache[1] != self._mutations
                 or cache[2] != len(self.validators)):
-            cache = (self.validators, self._mutations, len(self.validators),
-                     build())
-            self.__dict__[name] = cache
+            return None
         return cache[3]
+
+    def _keep(self, name: str, value):
+        self.__dict__[name] = (self.validators, self._mutations,
+                               len(self.validators), value)
+        return value
+
+    def _memo(self, name: str, build):
+        """``build()``'s result (never None), kept under ``_kept``'s rule."""
+        value = self._kept(name)
+        return self._keep(name, build()) if value is None else value
 
     def _addr_index(self) -> dict:
         """address -> index, rebuilt whenever the validators list object is
@@ -643,21 +657,44 @@ class ValidatorSet:
 
     # -- proto ------------------------------------------------------------
 
-    def encode(self) -> bytes:
-        from ..libs import protowire as pw
+    def _encoded_rows(self) -> bytes:
+        """Field 1 of ``encode``, every validator's framed encoding, built
+        once for as long as the set stays what it was: kept under
+        _addr_index's rule (list identity, ``_mutations``, length) AND the
+        proposer priorities the rows were built from, which rotate without
+        a structural mutation, so they are compared themselves on every
+        use (whoever wrote them, a mutator or a caller). ``copy()`` carries
+        the kept rows: a State's three sets are copies of one another a
+        height apart, so a set is encoded once in its life, not once per
+        State record it appears in. One pass from the rows' prefixes
+        (address, key, power: kept apart under the structural rule alone,
+        since priorities rotate every height and they do not) and the
+        priority varints, no Writer per row."""
+        vals = self.validators
+        prios = tuple(map(_PRIORITY_OF, vals))
+        rows = self._kept("_rows_cache")
+        if rows is not None and rows[0] == prios:
+            encode_stats["valset_encodes_reused"] += 1
+            return rows[1]
+        encode_stats["valset_encodes_built"] += 1
+        prefixes = self._memo(
+            "_prefix_cache", lambda: [v.encode_prefix() for v in vals])
+        varint = pw.encode_varint
+        body = pw.repeated_message(1, [
+            prefix + PRIORITY_TAG + varint(pp) if pp else prefix
+            for prefix, pp in zip(prefixes, prios)])
+        self._keep("_rows_cache", (prios, body))
+        return body
 
+    def encode(self) -> bytes:
         w = pw.Writer()
-        for v in self.validators:
-            w.message(1, v.encode())
         if self.proposer is not None:
             w.message(2, self.proposer.encode())
         w.varint(3, self.total_voting_power())
-        return w.finish()
+        return self._encoded_rows() + w.finish()
 
     @staticmethod
     def decode(data: bytes) -> "ValidatorSet":
-        from ..libs import protowire as pw
-
         vs = ValidatorSet()
         for fn, _wt, v in pw.iter_fields(data):
             if fn == 1:

@@ -462,3 +462,160 @@ def test_fast_sync_survives_tampered_block_response(one_val_genesis, monkeypatch
         assert fresh.state_store.load().last_block_id == chain_a[0].last_block_id
 
     asyncio.run(run())
+
+
+# -- encode once: what a sync writes is what the row-by-row encoders wrote ----
+
+def _four_val_chain(n_blocks):
+    """A 4-validator kvstore chain, every precommit with a timestamp of its
+    own -> (genesis, the n_blocks + 1 blocks as a peer serves them)."""
+    pvs = [MockPV(crypto.Ed25519PrivKey.generate(bytes([0x30 + i]) * 32))
+           for i in range(4)]
+    by_addr = {pv.get_pub_key().address(): pv for pv in pvs}
+    genesis = GenesisDoc(
+        chain_id=CHAIN_ID, genesis_time_ns=1_700_000_000_000_000_000,
+        validators=[GenesisValidator(pv.get_pub_key(), 10 + i)
+                    for i, pv in enumerate(pvs)])
+    state = state_from_genesis(genesis)
+    conns = AppConns(local_client_creator(KVStoreApplication()))
+    conns.start()
+    state_store = StateStore(MemDB())
+    state_store.save(state)
+    executor = BlockExecutor(state_store, conns.consensus, NoOpMempool(),
+                             EmptyEvidencePool(), BlockStore(MemDB()))
+    blocks, last_commit = [], Commit(0, 0, BlockID(), [])
+    try:
+        for h in range(1, n_blocks + 2):
+            block, parts = state.make_block(
+                h, [f"h{h}=v".encode()], last_commit, [],
+                state.validators.get_proposer().address)
+            bid = BlockID(block.hash(), parts.header())
+            vs = VoteSet(state.chain_id, h, 0, SignedMsgType.PRECOMMIT,
+                         state.validators)
+            for idx, val in enumerate(state.validators.validators):
+                v = Vote(SignedMsgType.PRECOMMIT, h, 0, bid,
+                         block.header.time_ns + 1 + idx * 1_000_003,
+                         val.address, idx)
+                by_addr[val.address].sign_vote(state.chain_id, v)
+                vs.add_vote(v)
+            blocks.append(block)
+            state, _ = executor.apply_block(state, bid, block)
+            last_commit = vs.make_commit()
+    finally:
+        conns.stop()
+    return genesis, blocks
+
+
+def _ref_block_encode(block):
+    """Block.encode with the LastCommit through the row-by-row reference."""
+    from test_commit_encode_once import ref_commit_encode
+    from tendermint_tpu.libs import protowire as pw
+    from tendermint_tpu.types.evidence import encode_evidence_list
+
+    w = pw.Writer()
+    w.message(1, block.header.encode())
+    w.message(2, block.data.encode())
+    w.message(3, encode_evidence_list(block.evidence))
+    w.message(4, ref_commit_encode(block.last_commit))
+    return w.finish()
+
+
+@pytest.mark.parametrize("downloaded_ahead", [17, 33],
+                         ids=["inline_windows", "prepared_ahead_windows"])
+def test_sync_writes_the_row_by_row_bytes_once_built(monkeypatch,
+                                                     downloaded_ahead):
+    """A fresh node syncs 40 blocks through the reactor's window loop on
+    the host backend. Every commit's row table and every height's validator
+    set is built once (``encode_stats``), no row goes the long way, and the
+    stored blocks, both stored commits of every height, the validator
+    records and the state record are byte for byte what the parent's
+    row-by-row encoders wrote."""
+    import json
+
+    from test_commit_encode_once import ref_commit_encode
+    from test_validator_set_encode_once import ref_valset_encode
+    from tendermint_tpu.types.basic import encode_stats
+    from tendermint_tpu.types.block import Block
+
+    monkeypatch.setenv("TMTPU_BATCH_BACKEND", "host")
+    n = 40
+    genesis, source = _four_val_chain(n)
+    # as received: decoded from the wire, nothing kept on any object
+    blocks = [Block.decode(_ref_block_encode(b)) for b in source]
+
+    conns = AppConns(local_client_creator(KVStoreApplication()))
+    conns.start()
+    state = state_from_genesis(genesis)
+    state_store = StateStore(MemDB())
+    state_store.save(state)
+    block_store = BlockStore(MemDB())
+    execu = BlockExecutor(state_store, conns.consensus, NoOpMempool(),
+                          EmptyEvidencePool(), block_store)
+    reactor = BlockchainReactor(state, execu, block_store, fast_sync=True)
+    reactor.pool = BlockPool(1)
+    before = dict(encode_stats)
+
+    async def drive():
+        while reactor.blocks_synced < n:
+            # the peer has as many blocks as the node is to hold ahead: one
+            # verify window (every window inline) or two (the next window
+            # prepared on the worker while this one applies)
+            top = min(n + 1, reactor.pool.height + downloaded_ahead - 1)
+            reactor.pool.set_peer_range("src", 1, top)
+            while reactor.pool.height + len(
+                    reactor.pool.peek_window(downloaded_ahead)) <= top:
+                for pid, h in reactor.pool.schedule_requests():
+                    reactor.pool.add_block(pid, blocks[h - 1])
+            applied = reactor.blocks_synced
+            await reactor._process_window()
+            assert reactor.blocks_synced > applied
+
+    try:
+        asyncio.run(drive())
+    finally:
+        conns.stop()
+    assert reactor.state.last_block_height == n
+    stage = reactor.stage_breakdown()
+    assert (stage["pipelined_windows"] > 0) == (downloaded_ahead == 33)
+
+    got = {k: encode_stats[k] - before[k] for k in encode_stats}
+    # the commits touched: the LastCommit of blocks 1..n+1, each once. A
+    # window prepared ahead on the worker can meet the loop thread on the
+    # one commit both read (the next window's first): one more at most each
+    distinct = n + 1
+    slack = 0 if downloaded_ahead == 17 else stage["pipelined_windows"]
+    assert distinct <= got["commit_tables_built"] <= distinct + slack, got
+    assert got["commit_tables_reused"] >= 3 * (n - 1), got
+    assert got["commit_rows_by_row"] == 0, got
+    assert got["valset_encodes_built"] == n, got
+    assert got["valset_encodes_reused"] >= 2 * (n - 1), got
+
+    db = block_store._db
+    for h in range(1, n + 1):
+        meta = block_store.load_block_meta(h)
+        stored = b"".join(
+            block_store.load_block_part(h, i).bytes_
+            for i in range(meta.block_id.part_set_header.total))
+        assert stored == _ref_block_encode(source[h - 1]), h
+        assert meta.block_id.hash == source[h - 1].hash()
+        # the seen commit of h is block h+1's LastCommit
+        assert db.get(f"SC:{h}".encode()) == ref_commit_encode(
+            source[h].last_commit), h
+        assert db.get(f"C:{h - 1}".encode()) == ref_commit_encode(
+            source[h - 1].last_commit), h
+        assert source[h].header.last_commit_hash == \
+            block_store.load_seen_commit(h).hash()
+
+    record = json.loads(state_store._db.get(b"stateKey").decode())
+    for name in ("next_validators", "validators", "last_validators"):
+        assert record[name] == ref_valset_encode(
+            getattr(reactor.state, name)).hex(), name
+    full_records = 0
+    for h in range(1, n + 3):
+        rec = json.loads(state_store._db.get(
+            f"validatorsKey:{h}".encode()).decode())
+        if "set" in rec:
+            full_records += 1
+            assert rec["set"] == ref_valset_encode(
+                state_store.load_validators(h)).hex(), h
+    assert full_records >= 2  # genesis and the interval's materialized ones
