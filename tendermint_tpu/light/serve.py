@@ -298,9 +298,13 @@ class VerifyCoalescer:
         self._timer: Optional[asyncio.Task] = None
         self._verdicts: "collections.OrderedDict[Any, Any]" = \
             collections.OrderedDict()
+        # collect_s / replay_s: cumulative seconds of a flush's two host
+        # stages (_verify_many): candidate rows and their sign-bytes, and
+        # the scalar replay under the precomputed verdicts
         self.stats = {"requests": 0, "flushes": 0, "largest_flush": 0,
                       "coalesced_dupes": 0, "verdict_cache_hits": 0,
-                      "sheds": 0, "batched_sigs": 0, "verified_requests": 0}
+                      "sheds": 0, "batched_sigs": 0, "verified_requests": 0,
+                      "collect_s": 0.0, "replay_s": 0.0}
 
     async def submit(self, req: VerifyRequest):
         self.stats["requests"] += 1
@@ -372,11 +376,13 @@ class VerifyCoalescer:
         reqs = [g[0] for g in groups]
         loop = asyncio.get_running_loop()
         try:
-            results, nsigs = await loop.run_in_executor(
+            results, nsigs, collect_s, replay_s = await loop.run_in_executor(
                 None, self._verify_many, reqs)
         except Exception as e:  # defensive: never strand a future
-            results, nsigs = [e] * len(reqs), 0
+            results, nsigs, collect_s, replay_s = [e] * len(reqs), 0, 0.0, 0.0
         self.stats["batched_sigs"] += nsigs
+        self.stats["collect_s"] += collect_s
+        self.stats["replay_s"] += replay_s
         self.stats["verified_requests"] += len(reqs)
         for (req, futs), res in zip(groups, results):
             if req.cache_key is not None:
@@ -395,10 +401,37 @@ class VerifyCoalescer:
     def _verify_many(self, reqs: List[VerifyRequest]):
         """Runs in a worker thread: one batched device call over the union
         of candidate signatures, then the scalar spec replayed per request.
-        Returns ([None-or-exception per request], batched signature count)."""
+        Returns ([None-or-exception per request], batched signature count,
+        seconds collecting the candidates, seconds of the replay)."""
         from ..crypto.batch import precompute, precomputed_verdicts
-        from ..types.validator_set import _is_aggregated
         from .verifier import verify
+
+        t0 = time.perf_counter()
+        items = self._candidates(reqs)
+        collect_s = time.perf_counter() - t0
+        pre = precompute(items, plane="light",
+                         backend=self.backend) if items else {}
+        t0 = time.perf_counter()
+        token = precomputed_verdicts.set(pre)
+        try:
+            out = []
+            for r in reqs:
+                try:
+                    verify(r.trusted_sh, r.trusted_vals, r.untrusted_sh,
+                           r.untrusted_vals, r.trusting_period_s, r.now_ns,
+                           r.max_clock_drift_s, r.trust_level)
+                    out.append(None)
+                except Exception as e:
+                    out.append(e)
+        finally:
+            precomputed_verdicts.reset(token)
+        return out, len(items), collect_s, time.perf_counter() - t0
+
+    @staticmethod
+    def _candidates(reqs: List[VerifyRequest]) -> list:
+        """The distinct (pub, sign-bytes, signature) rows of the requests'
+        commits: what one device call verifies for the whole flush."""
+        from ..types.validator_set import _is_aggregated
 
         items = []
         seen = set()
@@ -421,22 +454,7 @@ class VerifyCoalescer:
                     continue
                 seen.add(k)
                 items.append((pub, msg, cs.signature))
-        pre = precompute(items, plane="light",
-                         backend=self.backend) if items else {}
-        token = precomputed_verdicts.set(pre)
-        try:
-            out = []
-            for r in reqs:
-                try:
-                    verify(r.trusted_sh, r.trusted_vals, r.untrusted_sh,
-                           r.untrusted_vals, r.trusting_period_s, r.now_ns,
-                           r.max_clock_drift_s, r.trust_level)
-                    out.append(None)
-                except Exception as e:
-                    out.append(e)
-        finally:
-            precomputed_verdicts.reset(token)
-        return out, len(items)
+        return items
 
     def stop(self) -> None:
         """Cancel the deadline timer and fail anything still queued with an
@@ -536,8 +554,9 @@ class LightServePlane:
         self.limiter = ClientLimiter(config.per_client_rate,
                                      config.per_client_burst,
                                      scoreboard=scoreboard)
+        # build_s: cumulative seconds of _build_request, the store loads
         self.stats = {"headers_served": 0, "verifies_served": 0,
-                      "prefetched": 0}
+                      "prefetched": 0, "build_s": 0.0}
 
     # -- admission ----------------------------------------------------------
 
@@ -640,7 +659,9 @@ class LightServePlane:
             raise KeyError(
                 f"need 0 < trusted_height < height <= {tip}, "
                 f"got trusted_height={trusted_height} height={height}")
+        t0 = time.perf_counter()
         req = self._build_request(trusted_height, height, trust_level, tip)
+        self.stats["build_s"] += time.perf_counter() - t0
         res = await self.coalescer.submit(req)
         self.stats["verifies_served"] += 1
         return res
