@@ -45,6 +45,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 #: is swapped for a tampered/forged one. Registered in libs/faults.
 TAMPER_SITE = "lightserve.lying_server"
 
+#: bound of ``LightServePlane.loaded``, in validator and commit rows
+LOADED_ROWS = 262_144
+
 _MISS = object()
 
 
@@ -148,15 +151,18 @@ class HeaderCache:
 
     Plain entries evict LRU-first; pinned entries (prefetched bisection
     midpoints) are only sacrificed when every resident entry is pinned —
-    capacity is a hard bound either way."""
+    capacity is a hard bound either way. ``capacity`` bounds the sum of the
+    resident entries' weights, one each unless ``put`` gives another."""
 
     def __init__(self, capacity: int = 1024):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.capacity = capacity
-        self._entries: "collections.OrderedDict[int, Any]" = \
+        self._entries: "collections.OrderedDict[Any, Any]" = \
             collections.OrderedDict()
         self._pinned: set = set()
+        self._weights: Dict[Any, int] = {}
+        self.weight = 0
         self.stats = {"hits": 0, "misses": 0, "evictions": 0}
 
     def __len__(self) -> int:
@@ -178,20 +184,33 @@ class HeaderCache:
         prefetcher asking "is it already resident?")."""
         return self._entries.get(height)
 
-    def put(self, height: int, value, pinned: bool = False) -> None:
+    def put(self, height: int, value, pinned: bool = False,
+            weight: int = 1) -> None:
         if height in self._entries:
             self._entries.move_to_end(height)
+            self.weight -= self._weights[height]
         self._entries[height] = value
+        self._weights[height] = weight
+        self.weight += weight
         if pinned:
             self._pinned.add(height)
-        while len(self._entries) > self.capacity:
+        while self.weight > self.capacity:
             victim = next((h for h in self._entries
                            if h not in self._pinned), None)
             if victim is None:  # everything pinned: oldest pin goes
                 victim = next(iter(self._entries))
-            self._pinned.discard(victim)
-            del self._entries[victim]
+            self._remove(victim)
             self.stats["evictions"] += 1
+
+    def drop_where(self, pred: Callable[[Any], bool]) -> None:
+        """Remove every entry whose key satisfies ``pred``."""
+        for key in [k for k in self._entries if pred(k)]:
+            self._remove(key)
+
+    def _remove(self, key) -> None:
+        self._pinned.discard(key)
+        del self._entries[key]
+        self.weight -= self._weights.pop(key)
 
 
 class ClientLimiter:
@@ -525,7 +544,21 @@ class ServeProvider:
 class LightServePlane:
     """The node's serving plane: header/commit cache with bisection-aware
     prefetch, the verification coalescer, and per-client admission —
-    behind the /light_header, /light_verify, /lightserve_status routes."""
+    behind the /light_header, /light_verify, /lightserve_status routes.
+
+    A verify request's four loads (the signed header and the validator set
+    at its trusted height and at its height) go through ``loaded``, an LRU
+    of the objects the stores gave, keyed by (kind, height) and shared by
+    every request, flush and round: a crowd asking about one tip loads
+    each height once, and the replay's memos (the set's hash, its verify
+    arrays) are built once per object. ``LOADED_ROWS`` bounds it in
+    validator and commit rows, which scale with the set: ~130 heights at
+    1,000 validators, ~870 at 150. A loaded row, with the memos the replay
+    keeps on its object, holds ~0.30 KB of host memory (tracemalloc over
+    a 1,000-validator chain), so a full cache holds ~79 MB. Below the tip
+    the stored objects never change; a seen commit is kept only while its
+    height is the tip, and a tip that falls drops every entry at or above
+    it."""
 
     def __init__(self, *, block_store, state_store, chain_id: str,
                  config, metrics=None):
@@ -535,6 +568,8 @@ class LightServePlane:
         self.cfg = config
         self.metrics = metrics
         self.cache = HeaderCache(capacity=config.cache_capacity)
+        self.loaded = HeaderCache(capacity=LOADED_ROWS)
+        self._loaded_tip = 0
         self.coalescer = VerifyCoalescer(
             flush_deadline_s=config.flush_deadline_ms / 1000.0,
             flush_max=config.flush_max,
@@ -671,7 +706,7 @@ class LightServePlane:
                        tip: int) -> VerifyRequest:
         from ..types.light_block import SignedHeader
 
-        def signed_header(h: int) -> SignedHeader:
+        def load_header(h: int) -> SignedHeader:
             meta = self.block_store.load_block_meta(h)
             if meta is None:
                 raise KeyError(f"no header at height {h}")
@@ -681,12 +716,25 @@ class LightServePlane:
                 raise KeyError(f"no commit at height {h}")
             return SignedHeader(meta.header, commit)
 
-        def vals(h: int):
+        def load_vals(h: int):
             v = self.state_store.load_validators(h)
             if v is None:
                 raise KeyError(f"no validator set at height {h}")
             return v
 
+        def signed_header(h: int) -> SignedHeader:
+            # the tip is served with its seen commit, a height below it
+            # with the canonical one (the next block's LastCommit)
+            return self._load("seen" if h == tip else "canonical", h,
+                              load_header, lambda sh: sh.commit.size())
+
+        def vals(h: int):
+            return self._load("vals", h, load_vals, lambda v: v.size())
+
+        self._follow_tip(tip)
+        # a pruned height answers as the stores do, even while it is loaded
+        if trusted_height < self.block_store.base():
+            raise KeyError(f"no header at height {trusted_height}")
         now_ns = time.time_ns()
         # verdicts are only reusable while the content is immutable
         # (canonical heights below the tip) and within a trusting-period
@@ -702,6 +750,30 @@ class LightServePlane:
             self.cfg.trusting_period_s, now_ns, self.cfg.max_clock_drift_s,
             trust_level, cache_key=cache_key)
 
+    def _load(self, kind: str, h: int, load: Callable[[int], Any],
+              rows: Callable[[Any], int]):
+        """The object ``load(h)`` gives, from ``loaded`` when it holds
+        (kind, h); a load that raises caches nothing."""
+        key = (kind, h)
+        obj = self.loaded.get(key)
+        if obj is not None:
+            return obj
+        obj = load(h)
+        self.loaded.put(key, obj, weight=max(1, rows(obj)))
+        return obj
+
+    def _follow_tip(self, tip: int) -> None:
+        """Keep ``loaded`` to what the stores give at ``tip``. A seen commit
+        is served only while its height is the tip. A tip that falls (a
+        rollback) drops every height at or above it: those blocks are gone
+        or will be written anew, and the canonical commit at the new tip
+        came from the block above it."""
+        last, self._loaded_tip = self._loaded_tip, tip
+        if tip > last:
+            self.loaded.drop_where(lambda key: key == ("seen", last))
+        elif tip < last:
+            self.loaded.drop_where(lambda key: key[1] >= tip)
+
     # -- observability / lifecycle ------------------------------------------
 
     def status(self) -> Dict[str, Any]:
@@ -711,6 +783,8 @@ class LightServePlane:
             "cache": dict(self.cache.stats,
                           resident=len(self.cache),
                           pinned=self.cache.pinned_count()),
+            "loaded": dict(self.loaded.stats, resident=len(self.loaded),
+                           rows=self.loaded.weight),
             "limiter": dict(self.limiter.stats),
         }
 

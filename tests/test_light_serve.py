@@ -441,6 +441,9 @@ class _BlockStoreStub:
     def height(self):
         return max(self.blocks)
 
+    def base(self):
+        return min(self.blocks)
+
     def load_block_meta(self, h):
         from types import SimpleNamespace
         lb = self.blocks.get(h)

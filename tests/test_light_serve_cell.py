@@ -210,7 +210,8 @@ def test_new_counters_grow_with_every_flush_and_keep_status(cell,
     assert {k: set(v) for k, v in before.items()} == {
         "served": old["served"] | {"build_s"},
         "coalescer": old["coalescer"] | {"collect_s", "replay_s"},
-        "cache": old["cache"], "limiter": old["limiter"]}
+        "cache": old["cache"], "limiter": old["limiter"],
+        "loaded": {"hits", "misses", "evictions", "resident", "rows"}}
     tip = data["pool"][-1]
     for _ in range(2):
         last = L.program_counters(plane)
