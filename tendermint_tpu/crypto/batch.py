@@ -106,7 +106,9 @@ def device_threshold() -> int:
 
 # verdicts precomputed by a wider batching scope (e.g. the light client's
 # chain-batched verifier): (pk_bytes, msg, sig) -> bool. Consulted before any
-# dispatch so an enclosing batch costs ONE device call total.
+# dispatch so an enclosing batch costs ONE device call total. A batch the
+# map answers in part verifies only the rest (routed by the whole batch's
+# size) and writes those verdicts into the map.
 precomputed_verdicts: "contextvars.ContextVar[Optional[Dict]]" = \
     contextvars.ContextVar("tmtpu_precomputed_verdicts", default=None)
 
@@ -134,6 +136,8 @@ stats = {
     "host_batches": 0, "host_sigs": 0,
     "device_batches": 0, "device_sigs": 0,
     "precomputed_batches": 0, "precomputed_sigs": 0,
+    # triples a precomputed map in scope did not hold: verified here
+    "precomputed_misses": 0,
     "largest_batch": 0,
     # batches that came in through add_columns: verified on the device from
     # their columns, no row built / rows built after all (host route,
@@ -258,10 +262,13 @@ class BatchVerifier:
 
         stats["largest_batch"] = max(stats["largest_batch"], n)
         pre = precomputed_verdicts.get()
+        n_route = n     # the size the route is chosen by
+        held = None     # (the map's verdicts, positions it lacked)
         if pre is not None:
             m = rows()
             hits = [pre.get((pks[i], m[i], sigs[i])) for i in range(n)]
-            if all(h is not None for h in hits):
+            miss = [i for i, h in enumerate(hits) if h is None]
+            if not miss:
                 out = np.array(hits, dtype=bool)
                 stats["precomputed_batches"] += 1
                 stats["precomputed_sigs"] += n
@@ -269,12 +276,27 @@ class BatchVerifier:
                 if metrics is not None:
                     metrics.precomputed_hits_total.labels(self.plane).inc()
                 return bool(out.all()), out
+            stats["precomputed_misses"] += len(miss)
+            asked = [(pks[i], m[i], sigs[i]) for i in miss]
+            if len(miss) < n:
+                # a verdict is a function of its triple alone: the map
+                # answers what it holds, and only the rest is verified,
+                # on the route the whole batch would have taken
+                stats["precomputed_sigs"] += n - len(miss)
+                held = (np.array([bool(h) for h in hits]), miss)
+                at = {i: j for j, i in enumerate(miss)}
+                non_ed = [(at[i], pk) for i, pk in non_ed if i in at]
+                pks = [pks[i] for i in miss]
+                msgs = [m[i] for i in miss]
+                sigs = [sigs[i] for i in miss]
+                columns = None
+                n = len(miss)
 
         backend = self._backend
         if backend == "auto":
             thr = (self._threshold if self._threshold is not None
                    else device_threshold())
-            backend = "jax" if n >= thr else "host"
+            backend = "jax" if n_route >= thr else "host"
         if backend == "jax" and not device_breaker.allow():
             # breaker OPEN: zero device attempts until the cooldown admits a
             # half-open probe; the host path keeps verifying meanwhile
@@ -387,4 +409,12 @@ class BatchVerifier:
                 if slots:
                     metrics.pad_waste_ratio.labels(self.plane).set(
                         (slots - n_ed) / slots)
+        if pre is not None:
+            # the map learns what was verified under it: a later batch in
+            # the same scope that asks for the same triple finds it there
+            pre.update(zip(asked, map(bool, out)))
+        if held is not None:
+            verdicts, miss = held
+            verdicts[miss] = out
+            out = verdicts
         return bool(out.all()), out

@@ -781,14 +781,30 @@ class BlocksyncMetrics:
         self.inline_windows_total = c(
             "blocksync", "inline_windows_total",
             "Windows verified inline (pipeline starved or first window).")
+        self.set_spanning_windows_total = c(
+            "blocksync", "set_spanning_windows_total",
+            "Windows verified in one batch across a validator-set change "
+            "(their headers claim more than one set).")
+        self.spanning_window_blocks_total = c(
+            "blocksync", "spanning_window_blocks_total",
+            "Blocks applied from windows that span a validator-set change.")
+        self.verdict_candidates_total = c(
+            "blocksync", "verdict_candidates_total",
+            "Signatures stage A verified ahead for the windows applied, "
+            "each under its set's key or a guessed one.")
+        self.verdict_misses_total = c(
+            "blocksync", "verdict_misses_total",
+            "Signatures the apply-time rules asked for that stage A had "
+            "not verified (a guessed key that was not the set's, a row "
+            "never guessed): verified then, on their own.")
         self.lookahead_stalls_total = c(
             "blocksync", "lookahead_stalls_total",
             "Iterations where the next window's blocks were not yet "
             "downloaded when the lookahead wanted to start.")
         self.stale_window_discards_total = c(
             "blocksync", "stale_window_discards_total",
-            "Prepared windows discarded because the pool or validator set "
-            "moved underneath them.")
+            "Prepared windows discarded because the pool moved underneath "
+            "them.")
         # -- adversarial resilience (libs/peerscore.py scoreboard) --------
         self.peer_bans_total = c(
             "blocksync", "peer_bans_total",
