@@ -319,11 +319,14 @@ class VerifyCoalescer:
             collections.OrderedDict()
         # collect_s / replay_s: cumulative seconds of a flush's two host
         # stages (_verify_many): candidate rows and their sign-bytes, and
-        # the scalar replay under the precomputed verdicts
+        # the scalar replay under the precomputed verdicts.
+        # commits_collected / commits_reused: request commits whose rows a
+        # flush built, and those it skipped as already built (_candidates)
         self.stats = {"requests": 0, "flushes": 0, "largest_flush": 0,
                       "coalesced_dupes": 0, "verdict_cache_hits": 0,
                       "sheds": 0, "batched_sigs": 0, "verified_requests": 0,
-                      "collect_s": 0.0, "replay_s": 0.0}
+                      "collect_s": 0.0, "replay_s": 0.0,
+                      "commits_collected": 0, "commits_reused": 0}
 
     async def submit(self, req: VerifyRequest):
         self.stats["requests"] += 1
@@ -395,11 +398,14 @@ class VerifyCoalescer:
         reqs = [g[0] for g in groups]
         loop = asyncio.get_running_loop()
         try:
-            results, nsigs, collect_s, replay_s = await loop.run_in_executor(
-                None, self._verify_many, reqs)
+            results, nsigs, commits, collect_s, replay_s = \
+                await loop.run_in_executor(None, self._verify_many, reqs)
         except Exception as e:  # defensive: never strand a future
-            results, nsigs, collect_s, replay_s = [e] * len(reqs), 0, 0.0, 0.0
+            results, nsigs, commits, collect_s, replay_s = \
+                [e] * len(reqs), 0, (0, 0), 0.0, 0.0
         self.stats["batched_sigs"] += nsigs
+        self.stats["commits_collected"] += commits[0]
+        self.stats["commits_reused"] += commits[1]
         self.stats["collect_s"] += collect_s
         self.stats["replay_s"] += replay_s
         self.stats["verified_requests"] += len(reqs)
@@ -421,12 +427,13 @@ class VerifyCoalescer:
         """Runs in a worker thread: one batched device call over the union
         of candidate signatures, then the scalar spec replayed per request.
         Returns ([None-or-exception per request], batched signature count,
-        seconds collecting the candidates, seconds of the replay)."""
+        (commits collected, commits reused), seconds collecting the
+        candidates, seconds of the replay)."""
         from ..crypto.batch import precompute, precomputed_verdicts
         from .verifier import verify
 
         t0 = time.perf_counter()
-        items = self._candidates(reqs)
+        items, commits = self._candidates(reqs)
         collect_s = time.perf_counter() - t0
         pre = precompute(items, plane="light",
                          backend=self.backend) if items else {}
@@ -444,36 +451,43 @@ class VerifyCoalescer:
                     out.append(e)
         finally:
             precomputed_verdicts.reset(token)
-        return out, len(items), collect_s, time.perf_counter() - t0
+        return out, len(items), commits, collect_s, time.perf_counter() - t0
 
     @staticmethod
-    def _candidates(reqs: List[VerifyRequest]) -> list:
+    def _candidates(reqs: List[VerifyRequest]
+                    ) -> Tuple[list, Tuple[int, int]]:
         """The distinct (pub, sign-bytes, signature) rows of the requests'
-        commits: what one device call verifies for the whole flush."""
+        commits: what one device call verifies for the whole flush. A
+        commit is collected once per flush for each (commit, set, chain)
+        object triple: the requests of a round share the objects the plane
+        loaded, and a repeat would add only rows already seen. Returns
+        (rows, (commits collected, commits reused))."""
         from ..types.validator_set import _is_aggregated
 
         items = []
         seen = set()
+        collected = set()
+        reused = 0
         for r in reqs:
             commit = r.untrusted_sh.commit
             if _is_aggregated(commit):
                 continue  # BLS aggregates pair inline in the scalar replay
             chain_id = r.trusted_sh.header.chain_id
-            nvals = len(r.untrusted_vals.validators)
-            for idx, cs in enumerate(commit.signatures):
-                # malformed shapes are NOT pre-verified: the replay's
-                # structural checks raise the same typed error as the
-                # scalar path (its cache misses fall back to host verify)
-                if not cs.for_block() or idx >= nvals:
-                    continue
-                pub = r.untrusted_vals.validators[idx].pub_key
-                msg = commit.vote_sign_bytes(chain_id, idx)
-                k = (pub.bytes(), msg, cs.signature)
+            key = (id(commit), id(r.untrusted_vals), chain_id)
+            if key in collected:
+                reused += 1
+                continue
+            collected.add(key)
+            vals = r.untrusted_vals.validators
+            for idx, msg in _for_block_rows(commit, chain_id, len(vals)):
+                pub = vals[idx].pub_key
+                sig = commit.signatures[idx].signature
+                k = (pub.bytes(), msg, sig)
                 if k in seen:
                     continue
                 seen.add(k)
-                items.append((pub, msg, cs.signature))
-        return items
+                items.append((pub, msg, sig))
+        return items, (len(collected), reused)
 
     def stop(self) -> None:
         """Cancel the deadline timer and fail anything still queued with an
@@ -489,6 +503,30 @@ class VerifyCoalescer:
                 fut.set_exception(ShedError("shutdown"))
                 # nobody may await a shut-down future; don't warn about it
                 fut.exception()
+
+
+def _for_block_rows(commit, chain_id: str, nvals: int):
+    """(row index, sign-bytes) of the commit's for-block rows that have a
+    validator in a set of ``nvals``, in row order, cut from the commit's
+    memoised vectorised table (Commit.vote_sign_bytes_all, which the
+    replay's batch verify reads too). Malformed shapes are NOT
+    pre-verified: the replay's structural checks raise the same typed
+    error as the scalar path (its cache misses fall back to host verify).
+    A commit holding a row the table cannot encode (a flag no byte or
+    BlockIDFlag holds) takes the per-row encoder, which skips that row."""
+    import numpy as np
+
+    from ..types.block import BlockIDFlag
+
+    try:
+        table = commit.vote_sign_bytes_all(chain_id)
+        flags = commit.block_id_flags()
+    except ValueError:
+        return [(idx, commit.vote_sign_bytes(chain_id, idx))
+                for idx, cs in enumerate(commit.signatures[:nvals])
+                if cs.for_block()]
+    idxs = np.flatnonzero(flags[:nvals] == BlockIDFlag.COMMIT).tolist()
+    return [(idx, table[idx]) for idx in idxs]
 
 
 # -- serving surfaces --------------------------------------------------------

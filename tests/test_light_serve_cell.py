@@ -197,7 +197,8 @@ def test_refusing_any_bad_row_is_not_correct(cell, device_standin,
 def test_new_counters_grow_with_every_flush_and_keep_status(cell,
                                                            device_standin):
     """build_s, collect_s and replay_s grow with every round; every key the
-    plane's status() returned before them is there, beside them."""
+    plane's status() returned before them is there, beside them and the
+    coalescer's commit counters."""
     data, _ = cell
     plane = data["plane"]
     before = plane.status()
@@ -209,7 +210,9 @@ def test_new_counters_grow_with_every_flush_and_keep_status(cell,
            "limiter": {"admitted", "rate_sheds", "ban_sheds"}}
     assert {k: set(v) for k, v in before.items()} == {
         "served": old["served"] | {"build_s"},
-        "coalescer": old["coalescer"] | {"collect_s", "replay_s"},
+        "coalescer": old["coalescer"] | {"collect_s", "replay_s",
+                                         "commits_collected",
+                                         "commits_reused"},
         "cache": old["cache"], "limiter": old["limiter"],
         "loaded": {"hits", "misses", "evictions", "resident", "rows"}}
     tip = data["pool"][-1]
@@ -225,6 +228,12 @@ def test_new_counters_grow_with_every_flush_and_keep_status(cell,
         before["coalescer"]["verified_requests"] + 16)
     assert after["served"]["verifies_served"] == (
         before["served"]["verifies_served"] + 16)
+    # each round's requests share the tip's loaded commit: one collection
+    # a flush, every other request of it reuses that
+    assert after["coalescer"]["commits_collected"] == (
+        before["coalescer"]["commits_collected"] + 2)
+    assert after["coalescer"]["commits_reused"] == (
+        before["coalescer"]["commits_reused"] + 14)
 
 
 #: the per-layer metric that reads each of the plane's new counters
